@@ -27,6 +27,7 @@ import jax, jax.numpy as jnp
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import lm_batch
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import init_params
 from repro.optim import sgd
@@ -39,7 +40,7 @@ data_ax, model_ax, start, steps, ckpt = (
     sys.argv[5])
 cfg = get_config("qwen3-8b").scaled_down().with_tt(mode="tt", rank=8,
                                                    embed_rank=8)
-mesh = jax.make_mesh((data_ax, model_ax), ("data", "model"))
+mesh = make_mesh((data_ax, model_ax), ("data", "model"))
 opt = sgd(1e-2)
 train_step = make_train_step(cfg, opt)
 

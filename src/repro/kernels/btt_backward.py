@@ -56,8 +56,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 from .btt_linear import DEFAULT_TK, DEFAULT_TN, VMEM_BUDGET, _round_up
 
 __all__ = [
@@ -319,6 +317,7 @@ def btt_backward_pallas(x: jax.Array, gy: jax.Array, b: jax.Array,
 
     gx, ga, gb = pl.pallas_call(
         kern,
+        name="btt_backward",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -337,7 +336,7 @@ def btt_backward_pallas(x: jax.Array, gy: jax.Array, b: jax.Array,
         ],
         # Both grid axes carry accumulation state (ga/gb revisit across k,
         # t across n) — neither may be parallelized.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,

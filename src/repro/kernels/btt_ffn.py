@@ -54,8 +54,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 from .btt_linear import (
     DEFAULT_TK,
     VMEM_BUDGET,
@@ -430,12 +428,13 @@ def btt_ffn_pallas(x: jax.Array, b1: jax.Array, a1: jax.Array,
     y = pl.pallas_call(
         functools.partial(_ffn_fwd_kernel, act=act, f_logical=f_logical,
                           gated=gated, quant=scales is not None),
+        name="btt_ffn_fwd",
         grid=(kp // tk,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tk, mp), lambda k: (k, 0)),
         out_shape=jax.ShapeDtypeStruct((kp, mp), out_dtype),
         scratch_shapes=[pltpu.VMEM((tk, fp), out_dtype)],  # the hidden tile
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -534,13 +533,14 @@ def btt_ffn_bwd_pallas(x: jax.Array, gy: jax.Array, b1: jax.Array,
     outs = pl.pallas_call(
         functools.partial(_ffn_bwd_kernel, act=act, f_logical=f_logical,
                           gated=gated, quant=scales is not None),
+        name="btt_ffn_bwd",
         grid=(kp // tk,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         # The K axis carries accumulation state (ga/gb revisit every step).
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

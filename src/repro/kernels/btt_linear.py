@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.core.cost_model import sublane as _sublane
 
 __all__ = ["btt_linear_pallas", "choose_tiles", "DEFAULT_TK", "DEFAULT_TN",
@@ -195,12 +194,13 @@ def btt_linear_pallas(x: jax.Array, b: jax.Array, a: jax.Array, *,
 
     y = pl.pallas_call(
         kern,
+        name="btt_linear",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tk, mp), lambda k, n: (k, 0)),
         out_shape=jax.ShapeDtypeStruct((kp, mp), out_dtype),
         scratch_shapes=[pltpu.VMEM((tk, rp), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 __all__ = ["flash_attention_pallas", "DEFAULT_TQ", "DEFAULT_TK"]
 
 DEFAULT_TQ = 256
@@ -95,8 +93,8 @@ def _kernel(q_ref, k_ref, v_ref, *refs,
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
         if emit_stats:
-            mo_ref[0] = m_ref[...][:, 0]
-            lo_ref[0] = l_ref[...][:, 0]
+            mo_ref[0] = m_ref[...]
+            lo_ref[0] = l_ref[...]
 
 
 def _round_up(v: int, m: int) -> int:
@@ -138,15 +136,18 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out_specs = [pl.BlockSpec((1, tq, dp_), lambda h, i, j: (h, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((BH, sp, dp_), q.dtype)]
     if return_residuals:
-        out_specs += [pl.BlockSpec((1, tq), lambda h, i, j: (h, i)),
-                      pl.BlockSpec((1, tq), lambda h, i, j: (h, i))]
-        out_shape += [jax.ShapeDtypeStruct((BH, sp), jnp.float32),
-                      jax.ShapeDtypeStruct((BH, sp), jnp.float32)]
+        # The statistics carry a trailing unit axis inside the launch: a
+        # (1, tq) block of a (BH, S) array breaks Mosaic's rule that a
+        # block's last two dims be (8, 128)-aligned or whole.
+        stat = pl.BlockSpec((1, tq, 1), lambda h, i, j: (h, i, 0))
+        out_specs += [stat, stat]
+        out_shape += [jax.ShapeDtypeStruct((BH, sp, 1), jnp.float32)] * 2
 
     res = pl.pallas_call(
         functools.partial(_kernel, nk=nk, tq=tq, tk=tk, scale=scale,
                           causal=causal, window=window, s_real=S,
                           emit_stats=return_residuals),
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tq, dp_), lambda h, i, j: (h, i, 0)),
@@ -160,12 +161,12 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((tq, 1), jnp.float32),     # l
             pltpu.VMEM((tq, dp_), jnp.float32),   # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(qp, kp, vp)
     if return_residuals:
         out, m, l = res
-        return out[:, :S, :D], m[:, :S], l[:, :S]
+        return out[:, :S, :D], m[:, :S, 0], l[:, :S, 0]
     return res[0][:, :S, :D]

@@ -24,7 +24,7 @@ Grid = (B·KVh, G·S/TQ, S/TK) with the KV axis innermost; axis 1 enumerates
 
   q/do/o block (1, TQ, D)  — index ``(h·G + t//nq, t%nq)``: fetched once per
                              ``t`` (constant across the inner KV axis)
-  m/l block    (1, TQ) f32 — the forward's saved softmax statistics
+  m/l block    (1, TQ, 1) f32 — the forward's saved softmax statistics
   k/v block    (1, TK, D)  — streamed along the inner axis
   dq block     (1, TQ, D) f32 — index constant across the inner axis: the
                              block stays in VMEM, accumulates over KV steps,
@@ -63,8 +63,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 from .btt_linear import VMEM_BUDGET, _round_up
 from .flash_attention import DEFAULT_TK, DEFAULT_TQ, NEG_INF
@@ -207,8 +206,8 @@ def _bwd_kernel(q_ref, do_ref, o_ref, m_ref, l_ref, k_ref, v_ref,
         o = o_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)              # (TK, D)
         v = v_ref[0].astype(jnp.float32)
-        m = m_ref[0][:, None]                         # (TQ, 1) f32
-        l = l_ref[0][:, None]
+        m = m_ref[0]                                  # (TQ, 1) f32
+        l = l_ref[0]
 
         qpos = iq * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
         kpos = ik * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
@@ -278,8 +277,10 @@ def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
 
     qp, dop, op = pad3(q), pad3(do), pad3(o)
     kp, vp = pad3(k), pad3(v)
-    mp = jnp.pad(m.astype(jnp.float32), ((0, 0), (0, sp - S)))
-    lp = jnp.pad(l.astype(jnp.float32), ((0, 0), (0, sp - S)))
+    # (BH, sp, 1): a trailing unit axis keeps the (1, tq, 1) stat blocks
+    # within Mosaic's block rules (see flash_attention_pallas).
+    mp = jnp.pad(m.astype(jnp.float32), ((0, 0), (0, sp - S)))[..., None]
+    lp = jnp.pad(l.astype(jnp.float32), ((0, 0), (0, sp - S)))[..., None]
 
     nq, nk = sp // tq, sp // tk
     grid = (BKV, group * nq, nk)
@@ -288,19 +289,20 @@ def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         return (h * g + t // nq_, t % nq_, 0)
 
     def stat_map(h, t, j, g=group, nq_=nq):
-        return (h * g + t // nq_, t % nq_)
+        return (h * g + t // nq_, t % nq_, 0)
 
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, nq=nq, nk=nk, tq=tq, tk=tk,
                           scale=scale, causal=causal, window=window,
                           s_real=S),
+        name="flash_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tq, dp), q_map),               # q
             pl.BlockSpec((1, tq, dp), q_map),               # do
             pl.BlockSpec((1, tq, dp), q_map),               # o
-            pl.BlockSpec((1, tq), stat_map),                # m
-            pl.BlockSpec((1, tq), stat_map),                # l
+            pl.BlockSpec((1, tq, 1), stat_map),             # m
+            pl.BlockSpec((1, tq, 1), stat_map),             # l
             pl.BlockSpec((1, tk, dp), lambda h, t, j: (h, j, 0)),   # k
             pl.BlockSpec((1, tk, dp), lambda h, t, j: (h, j, 0)),   # v
         ],
@@ -317,7 +319,7 @@ def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         # Axis 0 (KV heads) owns disjoint accumulators -> parallel; axes
         # 1/2 carry accumulation state (dk/dv revisit across t, dq across
         # ik) and must stay sequential.
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

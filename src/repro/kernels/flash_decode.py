@@ -46,8 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 from .btt_linear import VMEM_BUDGET, _round_up
 from .flash_attention import NEG_INF
 
@@ -218,9 +216,10 @@ def flash_decode_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     o = pl.pallas_call(
         functools.partial(_kernel, np_max=np_max, page=P, scale=scale,
                           window=window),
+        name="flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, gp, dp), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -49,6 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import quant as _quant
 from repro.core.cost_model import sublane as _cm_sublane
 
+from .ops import kernel_interpret_default
+
 __all__ = [
     "fused_sgd_update",
     "fused_adamw_update",
@@ -139,10 +141,8 @@ def _adamw_kernel(scal_ref, p_ref, m_ref, v_ref, g_ref,
                   o_ref, om_ref, ov_ref, *,
                   b1: float, b2: float, eps: float, weight_decay: float):
     lr = scal_ref[0, 0]
-    t = scal_ref[0, 1]
-    # Bias correction computed IN-KERNEL from the step scalar.
-    bc1 = 1.0 - b1 ** t
-    bc2 = 1.0 - b2 ** t
+    bc1 = scal_ref[0, 2]
+    bc2 = scal_ref[0, 3]
     g = g_ref[...]
     m = b1 * m_ref[...] + (1.0 - b1) * g
     v = b2 * v_ref[...] + (1.0 - b2) * jnp.square(g)
@@ -155,7 +155,7 @@ def _adamw_kernel(scal_ref, p_ref, m_ref, v_ref, g_ref,
     o_ref[...] = (p - step).astype(o_ref.dtype)
 
 
-def _pu_call(kernel, scal: jax.Array, bufs: Sequence[jax.Array],
+def _pu_call(kernel, name: str, scal: jax.Array, bufs: Sequence[jax.Array],
              n_outs: int, br: int, interpret: bool) -> tuple[jax.Array, ...]:
     """Launch a PU kernel over flat (rows_p, lanes) buffers.
 
@@ -170,9 +170,9 @@ def _pu_call(kernel, scal: jax.Array, bufs: Sequence[jax.Array],
     blk = pl.BlockSpec((br, lanes), lambda i: (i, 0))
     out = pl.pallas_call(
         kernel,
+        name=name,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0),
-                               memory_space=pltpu.SMEM)]
+        in_specs=[_scal_spec()]
         + [blk] * len(bufs),
         out_specs=[blk] * n_outs,
         out_shape=[jax.ShapeDtypeStruct(b.shape, b.dtype)
@@ -184,10 +184,6 @@ def _pu_call(kernel, scal: jax.Array, bufs: Sequence[jax.Array],
     return tuple(out)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _dtype_groups(leaves: Sequence[jax.Array]) -> list[list[int]]:
     """Indices of ``leaves`` grouped by dtype (one kernel launch per group)."""
     groups: dict[Any, list[int]] = {}
@@ -196,9 +192,19 @@ def _dtype_groups(leaves: Sequence[jax.Array]) -> list[list[int]]:
     return list(groups.values())
 
 
-def _scal(lr_t, t=0.0) -> jax.Array:
-    return jnp.stack([jnp.asarray(lr_t, jnp.float32),
-                      jnp.asarray(t, jnp.float32)]).reshape(1, 2)
+def _scal(lr_t, t=0.0, b1: float = 0.0, b2: float = 0.0) -> jax.Array:
+    """The SMEM scalar row ``[lr, t, 1 - b1**t, 1 - b2**t]``.
+
+    The AdamW bias corrections are computed here, outside the kernel:
+    Mosaic cannot lower ``powf``.
+    """
+    t = jnp.asarray(t, jnp.float32)
+    return jnp.stack([jnp.asarray(lr_t, jnp.float32), t,
+                      1.0 - b1 ** t, 1.0 - b2 ** t]).reshape(1, 4)
+
+
+def _scal_spec() -> pl.BlockSpec:
+    return pl.BlockSpec((1, 4), lambda i: (0, 0), memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +221,7 @@ def fused_sgd_update(params, grads, lr_t, *, momentum: float = 0.0,
     in f32, params cast back to their storage dtype).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernel_interpret_default()
     p_leaves, treedef = jax.tree.flatten(params)
     g_leaves = treedef.flatten_up_to(grads)
     mu_leaves = treedef.flatten_up_to(mu) if mu is not None else None
@@ -231,7 +237,8 @@ def fused_sgd_update(params, grads, lr_t, *, momentum: float = 0.0,
         gb = pack_leaves([g_leaves[i] for i in idx], jnp.float32, rows_p, lanes)
         shapes = [x.shape for x in group]
         if momentum == 0.0:
-            (ob,) = _pu_call(_sgd_kernel, scal, [pb, gb], 1, br, interpret)
+            (ob,) = _pu_call(_sgd_kernel, "fused_sgd", scal, [pb, gb], 1,
+                             br, interpret)
             outs = unpack_leaves(ob, shapes, [pdt] * len(group))
             for j, i in enumerate(idx):
                 new_p[i] = outs[j]
@@ -239,7 +246,8 @@ def fused_sgd_update(params, grads, lr_t, *, momentum: float = 0.0,
             mb = pack_leaves([mu_leaves[i] for i in idx], jnp.float32,
                              rows_p, lanes)
             kern = functools.partial(_sgd_momentum_kernel, momentum=momentum)
-            ob, omb = _pu_call(kern, scal, [pb, mb, gb], 2, br, interpret)
+            ob, omb = _pu_call(kern, "fused_sgd_momentum", scal,
+                               [pb, mb, gb], 2, br, interpret)
             outs = unpack_leaves(ob, shapes, [pdt] * len(group))
             mouts = unpack_leaves(omb, shapes, [jnp.float32] * len(group))
             for j, i in enumerate(idx):
@@ -255,11 +263,11 @@ def fused_adamw_update(params, grads, m, v, lr_t, t, *, b1: float,
                        interpret: bool | None = None):
     """One fused AdamW PU stage: ``(new_params, new_m, new_v)``.
 
-    ``t`` is the 1-based step (bias correction is computed in-kernel from
-    it); hyperparameters are compile-time constants.
+    ``t`` is the 1-based step (the bias corrections ride into the kernel
+    as SMEM scalars); hyperparameters are compile-time constants.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernel_interpret_default()
     p_leaves, treedef = jax.tree.flatten(params)
     g_leaves = treedef.flatten_up_to(grads)
     m_leaves = treedef.flatten_up_to(m)
@@ -267,7 +275,7 @@ def fused_adamw_update(params, grads, m, v, lr_t, t, *, b1: float,
     new_p: list = [None] * len(p_leaves)
     new_m: list = [None] * len(p_leaves)
     new_v: list = [None] * len(p_leaves)
-    scal = _scal(lr_t, t)
+    scal = _scal(lr_t, t, b1, b2)
     kern = functools.partial(_adamw_kernel, b1=b1, b2=b2, eps=eps,
                              weight_decay=weight_decay)
     for idx in _dtype_groups(p_leaves):
@@ -279,7 +287,8 @@ def fused_adamw_update(params, grads, m, v, lr_t, t, *, b1: float,
         mb = pack_leaves([m_leaves[i] for i in idx], jnp.float32, rows_p, lanes)
         vb = pack_leaves([v_leaves[i] for i in idx], jnp.float32, rows_p, lanes)
         gb = pack_leaves([g_leaves[i] for i in idx], jnp.float32, rows_p, lanes)
-        ob, omb, ovb = _pu_call(kern, scal, [pb, mb, vb, gb], 3, br, interpret)
+        ob, omb, ovb = _pu_call(kern, "fused_adamw", scal,
+                                [pb, mb, vb, gb], 3, br, interpret)
         shapes = [x.shape for x in group]
         outs = unpack_leaves(ob, shapes, [pdt] * len(group))
         mouts = unpack_leaves(omb, shapes, [jnp.float32] * len(group))
@@ -316,8 +325,8 @@ def _adamw_quant_kernel(scal_ref, pq_ref, ps_ref, m_ref, v_ref, g_ref,
     """One packed block of the quantized-master AdamW PU stage."""
     lr = scal_ref[0, 0]
     t = scal_ref[0, 1]
-    bc1 = 1.0 - b1 ** t
-    bc2 = 1.0 - b2 ** t
+    bc1 = scal_ref[0, 2]
+    bc2 = scal_ref[0, 3]
     g = g_ref[...]
     m = b1 * m_ref[...] + (1.0 - b1) * g
     v = b2 * v_ref[...] + (1.0 - b2) * jnp.square(g)
@@ -383,7 +392,7 @@ def fused_adamw_update_quant(pq, ps, mb, vb, gb, lr_t, t, *, fmt: str,
     entirely inside the kernel — no dense f32 parameter buffer touches HBM.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernel_interpret_default()
     rows_p, lanes = pq.shape
     n_blocks = ps.shape[0]
     br = rows_p // n_blocks
@@ -394,9 +403,9 @@ def fused_adamw_update_quant(pq, ps, mb, vb, gb, lr_t, t, *, fmt: str,
                              weight_decay=weight_decay, fmt=fmt)
     out = pl.pallas_call(
         kern,
+        name="fused_adamw_quant",
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0),
-                               memory_space=pltpu.SMEM),
+        in_specs=[_scal_spec(),
                   blk, sblk, blk, blk, blk],
         out_specs=[blk, sblk, blk, blk],
         out_shape=[jax.ShapeDtypeStruct(pq.shape, pq.dtype),
@@ -405,7 +414,7 @@ def fused_adamw_update_quant(pq, ps, mb, vb, gb, lr_t, t, *, fmt: str,
                    jax.ShapeDtypeStruct(vb.shape, vb.dtype)],
         input_output_aliases={1: 0, 2: 1, 3: 2, 4: 3},
         interpret=interpret,
-    )(_scal(lr_t, t), pq, ps, mb, vb, gb)
+    )(_scal(lr_t, t, b1, b2), pq, ps, mb, vb, gb)
     return tuple(out)
 
 
@@ -577,9 +586,8 @@ def _sketched_math(scal_ref, vso_ref, mso_ref, vsd_ref, msd_ref, g_ref,
         oms_ref[...] = msd_ref[...]
 
     lr = scal_ref[0, 0]
-    t = scal_ref[0, 1]
-    bc1 = 1.0 - b1 ** t
-    bc2 = 1.0 - b2 ** t
+    bc1 = scal_ref[0, 2]
+    bc2 = scal_ref[0, 3]
     rows = jax.lax.broadcasted_iota(jnp.int32, (br, lanes), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (br, lanes), 1)
     local = (rows * lanes + cols + i * br * lanes).reshape(-1)
@@ -669,9 +677,9 @@ def _sketched_call(kern, scal, pb, gb, vs_old, ms_old, vs_seed, ms_seed,
     skb = pl.BlockSpec(vs_old.shape, lambda i: (0, 0))
     out = pl.pallas_call(
         kern,
+        name="fused_adamw_sketched",
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0),
-                               memory_space=pltpu.SMEM),
+        in_specs=[_scal_spec(),
                   blk, skb, skb, skb, skb, blk],
         out_specs=[blk, skb, skb],
         out_shape=[jax.ShapeDtypeStruct(pb.shape, pb.dtype),
@@ -698,12 +706,12 @@ def sketched_adamw_update(params, grads, vs, ms, lr_t, t, *, b1: float,
     the hash assignment is stable across steps and checkpoints.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernel_interpret_default()
     depth, width = vs.shape
     p_leaves, treedef = jax.tree.flatten(params)
     g_leaves = treedef.flatten_up_to(grads)
     new_p: list = [None] * len(p_leaves)
-    scal = _scal(lr_t, t)
+    scal = _scal(lr_t, t, b1, b2)
     kern = functools.partial(
         _sketched_adamw_kernel, b1=b1, b2=b2, eps=eps,
         weight_decay=weight_decay, depth=depth, width=width)
@@ -744,7 +752,7 @@ def sketched_adamw_update_quant(pq, ps, vs, ms, gb, n_valid: int, lr_t, t,
     ``gb`` the (rows_p, LANES) f32 packed gradient buffer.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = kernel_interpret_default()
     depth, width = vs.shape
     rows_p, lanes = pq.shape
     n_blocks = ps.shape[0]
@@ -758,9 +766,9 @@ def sketched_adamw_update_quant(pq, ps, vs, ms, gb, n_valid: int, lr_t, t,
     skb = pl.BlockSpec(vs.shape, lambda i: (0, 0))
     out = pl.pallas_call(
         kern,
+        name="fused_adamw_sketched_quant",
         grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0),
-                               memory_space=pltpu.SMEM),
+        in_specs=[_scal_spec(),
                   blk, sblk, skb, skb, skb, skb, blk],
         out_specs=[blk, sblk, skb, skb],
         out_shape=[jax.ShapeDtypeStruct(pq.shape, pq.dtype),
@@ -770,7 +778,8 @@ def sketched_adamw_update_quant(pq, ps, vs, ms, gb, n_valid: int, lr_t, t,
         input_output_aliases={1: 0, 2: 1},
         # seed sketches (zeros / b1-decayed) ride as the vsd/msd operands.
         interpret=interpret,
-    )(_scal(lr_t, t), pq, ps, vs, ms, jnp.zeros_like(vs), b1 * ms, gb)
+    )(_scal(lr_t, t, b1, b2), pq, ps, vs, ms, jnp.zeros_like(vs),
+      b1 * ms, gb)
     return tuple(out)
 
 

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 __all__ = ["ttm_embed_pallas", "DEFAULT_TOKENS_BLOCK"]
 
 DEFAULT_TOKENS_BLOCK = 128
@@ -97,7 +95,7 @@ def ttm_embed_pallas(oh: tuple[jax.Array, jax.Array, jax.Array],
         ],
         out_specs=pl.BlockSpec((tk, H), lambda k: (k, 0)),
         out_shape=jax.ShapeDtypeStruct((kp, H), dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

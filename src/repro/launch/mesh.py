@@ -16,42 +16,50 @@ meshes (more pods, separate "expert"/"seq" axes) need no model-code changes.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
 
-__all__ = ["make_production_mesh", "make_host_mesh", "SINGLE_POD", "MULTI_POD"]
+__all__ = ["make_mesh", "make_production_mesh", "make_host_mesh",
+           "SINGLE_POD", "MULTI_POD"]
 
 SINGLE_POD = ((16, 16), ("data", "model"))
 MULTI_POD = ((2, 16, 16), ("pod", "data", "model"))
 
 
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    JAX 0.9 builds Explicit axes by default.  The training step relies on
+    GSPMD propagation (``meshctx.constrain``'s ``with_sharding_constraint``,
+    gathers over data-sharded ids), which Explicit axes reject.  Every mesh
+    in the repo is built here.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, stage: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU examples).
+    """Mesh over the first ``stage * data * model`` local devices.
 
-    Axis requests are clamped and validated, not trusted: zero/negative
-    requests clamp to 1 (``data=0`` used to ZeroDivisionError on the
-    ``n // data`` fit check), oversubscribed requests shrink model-first
-    then data to fit the device count, and every axis ends up >= 1.
+    Zero or negative requests clamp to 1.  A request for more devices than
+    exist RAISES: running on fewer devices than asked for would change the
+    run without saying so.
 
     ``stage > 1`` builds the pipeline topology ("stage", "data", "model")
-    used by ``launch.steps.make_pipeline_train_step``.  Unlike data/model,
-    a stage request that cannot be satisfied RAISES instead of clamping:
-    silently running a different pipeline depth than requested would change
-    the training program, not just its layout.
+    used by ``launch.steps.make_pipeline_train_step``.
     """
     n = len(jax.devices())
-    stage = max(int(stage), 1)
-    if stage > n or n % stage:
+    stage, data, model = (max(int(v), 1) for v in (stage, data, model))
+    if stage * data * model > n:
         raise ValueError(
-            f"stage={stage} does not divide the {n} available device(s)")
-    avail = n // stage
-    data = min(max(int(data), 1), avail)
-    model = min(max(int(model), 1), max(avail // data, 1))
+            f"stage={stage} x data={data} x model={model} needs "
+            f"{stage * data * model} devices; {n} available")
     if stage > 1:
-        return jax.make_mesh((stage, data, model),
-                             ("stage", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((stage, data, model), ("stage", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -34,6 +34,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.data import lm_batch
 from repro.kernels.flash_decode import DEFAULT_PAGE_SIZE
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_decode_step, make_prefill, prepare_decode_cache
 from repro.models.transformer import init_params, num_params
@@ -335,4 +336,5 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

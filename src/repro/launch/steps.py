@@ -13,6 +13,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models.transformer import cache_struct, forward, loss_fn
@@ -22,7 +23,11 @@ __all__ = [
     "make_train_step", "make_ddp_train_step", "make_pipeline_train_step",
     "make_prefill", "make_decode_step",
     "make_inputs", "abstract_train_state", "prepare_decode_cache",
+    "PIPELINE_BATCH_SPEC",
 ]
+
+# The pipeline step's batch rows split over DP x row-TP.
+PIPELINE_BATCH_SPEC = PartitionSpec(("data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -209,7 +214,6 @@ def make_ddp_train_step(cfg: ModelConfig, opt: Optimizer, mesh, *,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.runtime.compress import compressed_allreduce_mean, ef_compress_tree
 
     def step(params, opt_state, ef, batch):
@@ -234,7 +238,7 @@ def make_ddp_train_step(cfg: ModelConfig, opt: Optimizer, mesh, *,
 
     rep = P()
     batch_spec = P("data")
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),
         out_specs=(rep, rep, rep, rep),
@@ -276,7 +280,6 @@ def make_pipeline_train_step(cfg: ModelConfig, opt: Optimizer, mesh, *,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.runtime.pipeline import (
         StagePartition,
         cycles_per_stage,
@@ -306,10 +309,9 @@ def make_pipeline_train_step(cfg: ModelConfig, opt: Optimizer, mesh, *,
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     rep = P()
-    batch_spec = P(("data", "model"))  # rows split over DP × row-TP
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
-        in_specs=(rep, rep, batch_spec),
+        in_specs=(rep, rep, PIPELINE_BATCH_SPEC),
         out_specs=(rep, rep, rep),
         check_vma=False,  # stage ppermute breaks the replication checker
     )

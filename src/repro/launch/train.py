@@ -28,8 +28,13 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.launch.steps import make_pipeline_train_step, make_train_step
+from repro.launch.steps import (
+    PIPELINE_BATCH_SPEC,
+    make_pipeline_train_step,
+    make_train_step,
+)
 from repro.models.transformer import init_params, num_params, param_bytes
 from repro.optim import adamw, master_view, sgd, warmup_cosine
 from repro.runtime import (
@@ -200,11 +205,14 @@ def main(argv=None) -> dict:
     print(f"[train] arch={cfg.name} tt={cfg.tt.mode} params={num_params(params):,} "
           f"({param_bytes(params)/1e6:.1f} MB) mesh={dict(mesh.shape)}")
 
+    sample = lm_batch(args.seed, 0, args.batch, args.seq, vocab)
     if pipelined:
         # shard_map owns the partitioning: params/opt state replicated,
-        # batch rows split over ("data", "model").  No GSPMD specs or
-        # device_put — the jitted step shards its own inputs.
-        psh = ssh = bsh = None
+        # batch rows split over ("data", "model").  The batch is placed
+        # with that spec; params and state are left for the step to shard.
+        psh = ssh = None
+        bsh = named_sharding_tree(
+            mesh, jax.tree.map(lambda _: PIPELINE_BATCH_SPEC, sample))
         step_fn = make_pipeline_train_step(
             cfg, opt, mesh, microbatches=args.microbatches,
             fused_bwd=args.fused_bwd)
@@ -215,7 +223,6 @@ def main(argv=None) -> dict:
                                      guard=args.guard)
         pspec = param_specs(cfg, params, mesh)
         sspec = opt_state_specs(cfg, opt_state, pspec, mesh)
-        sample = lm_batch(args.seed, 0, args.batch, args.seq, vocab)
         bspec = batch_specs(sample, mesh)
         psh = named_sharding_tree(mesh, pspec)
         ssh = named_sharding_tree(mesh, sspec)
@@ -262,7 +269,7 @@ def main(argv=None) -> dict:
     monitor = StragglerMonitor()
     cadence = CheckpointCadence(base_interval=max(args.steps // 4, 1),
                                 min_interval=max(args.steps // 10, 1))
-    losses = []
+    losses, grad_norms = [], []
     next_ckpt = None
     for step in range(start, args.steps):
         batch = {k: jnp.asarray(v) for k, v in
@@ -275,7 +282,8 @@ def main(argv=None) -> dict:
                                                  guard.controls())
         else:
             params, opt_state, metrics = step_fn(params, opt_state, batch)
-        loss = float(metrics["loss"])
+        host = jax.device_get(metrics)  # the step's one device->host sync
+        loss = float(host["loss"])
         dt = time.time() - t0
         flagged = monitor.observe(dt)
         action = "ok"
@@ -283,6 +291,7 @@ def main(argv=None) -> dict:
             params, opt_state, action = guard.observe(step, metrics, params,
                                                       opt_state)
         losses.append(loss)
+        grad_norms.append(float(host["grad_norm"]))
         if step % args.log_every == 0 or step == args.steps - 1:
             tag = "" if action == "ok" else f"  GUARD:{action.upper()}"
             print(f"[train] step {step:5d} loss {loss:.4f} "
@@ -298,13 +307,24 @@ def main(argv=None) -> dict:
         mgr.wait()
     out = {"final_loss": losses[-1] if losses else None,
            "first_loss": losses[0] if losses else None,
-           "straggler_flags": monitor.total_flags}
+           "losses": losses,
+           "grad_norms": grad_norms,
+           "straggler_flags": monitor.total_flags,
+           # The config, the jitted step, the final state and the last
+           # batch as placed, so a caller can check where they live and
+           # inspect the program that ran.
+           "cfg": cfg,
+           "step_fn": step_fn,
+           "params": params,
+           "opt_state": opt_state,
+           "batch": batch if losses else None}
     if guard is not None:
         out["guard"] = guard.report()
     return out
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     out = main()
     print(f"[train] done: first={out['first_loss']:.4f} "
           f"final={out['final_loss']:.4f}")

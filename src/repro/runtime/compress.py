@@ -20,8 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 __all__ = [
     "quantize_int8", "dequantize_int8",
     "compressed_allreduce_mean", "ef_compress_tree", "ef_init",
@@ -54,7 +52,7 @@ def compressed_allreduce_mean(x: jax.Array, axis_name: str) -> jax.Array:
     first quantization, so the compounding would go uncompensated.
     Bytes on wire per element per step: 1 (plus one f32 scale per tensor).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     perm = [(i, (i + 1) % n) for i in range(n)]

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.launch.mesh import make_mesh
 from repro.models.layers import rms_norm
 from repro.models.transformer import (
     _embed_inputs,
@@ -123,7 +124,7 @@ def cycles_per_stage(cfg: ModelConfig, stages: int) -> int:
 
 def make_pipeline_mesh(part: StagePartition):
     """(stage, data, model) mesh for ``part`` over the available devices."""
-    return jax.make_mesh((part.stages, part.dp, part.tp), PIPELINE_AXES)
+    return make_mesh((part.stages, part.dp, part.tp), PIPELINE_AXES)
 
 
 def pipeline_loss_and_grads(params, cfg: ModelConfig, batch: dict,

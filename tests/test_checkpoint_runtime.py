@@ -24,6 +24,7 @@ from repro.checkpoint import (
 )
 from repro.configs import SHAPES, get_config
 from repro.core import tt_linear_init
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_inputs
 from repro.models import init_params
 from repro.runtime import (
@@ -301,7 +302,7 @@ def _leaf_specs(tree):
                                   "mamba2-130m", "recurrentgemma-2b"])
 def test_param_specs_cover_every_leaf(arch):
     cfg = get_config(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     specs = param_specs(cfg, params, mesh)
     p_leaves = jax.tree.leaves(params)
@@ -313,7 +314,7 @@ def test_param_specs_cover_every_leaf(arch):
 
 def test_param_specs_tt_cores_replicated():
     cfg = get_config("qwen3-8b").with_tt(mode="tt")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     flat, _ = jax.tree_util.tree_flatten_with_path(params)
     specs = param_specs(cfg, params, mesh)
@@ -326,7 +327,7 @@ def test_param_specs_tt_cores_replicated():
 
 def test_batch_and_cache_specs():
     cfg = get_config("llama3-8b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = SHAPES["decode_32k"]
     kvr = kv_repeat_for_mesh(cfg, mesh)
     inputs = make_inputs(cfg, shape, kv_repeat=kvr)
@@ -338,7 +339,7 @@ def test_batch_and_cache_specs():
 
 
 def test_kv_repeat_rules():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert kv_repeat_for_mesh(get_config("llama3-8b"), mesh) >= 1
     # 16-way TP mesh requires fake devices; the divisor logic is pure:
     from repro.runtime.sharding import kv_repeat_for_mesh as f

@@ -73,7 +73,7 @@ def test_fused_sgd_matches_unfused_over_steps(tt_params, momentum):
 
 
 def test_fused_adamw_matches_unfused_over_steps(tt_params):
-    """Moment EMAs + in-kernel bias correction + weight decay, compounded
+    """Moment EMAs + bias correction (SMEM scalars) + weight decay, compounded
     over N steps, must track the pure-JAX path."""
     mk = lambda fused: adamw(1e-3, b1=0.9, b2=0.95, eps=1e-8,
                              weight_decay=0.01, fused=fused,

@@ -71,11 +71,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.runtime import compressed_allreduce_mean
 mesh = jax.make_mesh((8,), ("data",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
-f = shard_map(lambda v: compressed_allreduce_mean(v, "data"), mesh=mesh,
+f = jax.shard_map(lambda v: compressed_allreduce_mean(v, "data"), mesh=mesh,
               in_specs=P("data", None), out_specs=P("data", None),
               check_vma=False)
 y = f(x)

@@ -128,7 +128,6 @@ import json
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 from repro.runtime.compress import compressed_allreduce_mean
 
 out = {}
@@ -139,7 +138,7 @@ for n in (2, 4, 8):
     rng = np.random.default_rng(0)
     x = np.stack([(10.0 ** (i % 3)) * rng.standard_normal(512)
                   for i in range(n)]).astype(np.float32)
-    f = shard_map(lambda v: compressed_allreduce_mean(v[0], "data")[None],
+    f = jax.shard_map(lambda v: compressed_allreduce_mean(v[0], "data")[None],
                   mesh=mesh, in_specs=P("data"), out_specs=P("data"))
     got = np.asarray(jax.jit(f)(jnp.asarray(x)))
     exact = x.mean(axis=0)
@@ -208,6 +207,7 @@ def test_grad_norm_reported_without_clipping():
     import jax.numpy as jnp
 
     from repro.configs.atis_transformer import config_n
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import make_ddp_train_step, make_train_step
     from repro.models.transformer import init_params
     from repro.optim import sgd
@@ -229,7 +229,7 @@ def test_grad_norm_reported_without_clipping():
     gn = float(m["grad_norm"])
     assert gn > 0.0 and jnp.isfinite(gn), gn
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ddp = make_ddp_train_step(cfg, opt, mesh, compress=False, clip_norm=0.0)
     _, _, _, m2 = ddp(jax.tree.map(jnp.copy, params),
                       jax.tree.map(jnp.copy, state), ef_init(params), batch)
@@ -253,6 +253,55 @@ def test_make_host_mesh_clamps_and_validates():
         make_host_mesh(1, 1, stage=n + 1)
     assert dict(make_host_mesh(1, 1, stage=1).shape) == {"data": 1,
                                                          "model": 1}
+
+
+@pytest.mark.parametrize("request_axes", [(2, 1, 1), (1, 2, 1), (1, 1, 2)])
+def test_make_host_mesh_raises_past_device_count(request_axes):
+    import jax
+
+    from repro.launch.mesh import make_host_mesh
+
+    # Oversubscribing never shrinks the mesh: the run would otherwise use
+    # fewer devices than asked for without saying so.
+    n = len(jax.devices())
+    data, model, stage = (v * n for v in request_axes)
+    with pytest.raises(ValueError, match="devices"):
+        make_host_mesh(data, model, stage=stage)
+
+
+def test_meshes_have_auto_axes():
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_host_mesh, make_mesh
+
+    for mesh in (make_mesh((1, 1), ("data", "model")), make_host_mesh(1, 1)):
+        assert tuple(mesh.axis_types) == (AxisType.Auto, AxisType.Auto)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import compile_cache_dir
+
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache_dir() == compile_cache_dir() == os.path.join(
+        root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache_dir() == str(tmp_path)
+
+
+def test_compile_cache_off_outside_checkout(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+
+    # An installed copy resolves its "checkout root" to a directory with no
+    # pyproject.toml: no default cache there, unless the variable names one.
+    monkeypatch.setattr(compile_cache, "_ROOT", tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.compile_cache_dir() is None
+    assert compile_cache.enable_compile_cache() is None
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.compile_cache_dir() == str(tmp_path)
 
 
 def test_straggler_flag_rate_post_warmup():

@@ -15,6 +15,7 @@ from repro.core.ttm_embedding import (
     ttm_embedding_init,
     ttm_strategy_crossover,
 )
+from repro.launch.mesh import make_mesh
 from repro.models.moe import moe_apply, moe_init
 
 
@@ -26,7 +27,7 @@ def test_constrain_noop_without_mesh():
 
 
 def test_constrain_applies_and_degrades():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with activation_mesh(mesh):
         assert current_mesh() is mesh
         x = jnp.ones((4, 8))
@@ -40,7 +41,7 @@ def test_constrain_applies_and_degrades():
 
 
 def test_constrain_inside_jit():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     def f(x):
         return constrain(x * 2, "model") + 1
