@@ -1,0 +1,10 @@
+"""``btt_ffn_bwd``: a TT FFN block's backward; operands ``x, gy`` and the
+pairs, as ``btt_ffn_fwd``'s."""
+from bench.kernels.btt_ffn_fwd import shape
+from bench.work import tt_ffn
+
+
+def work(call, ctx):
+    K, d, f, ranks, item = shape(call, ctx, 2)
+    return (tt_ffn.backward(K, d, f, ranks, item)[0],
+            *tt_ffn.backward_bytes(K, d, f, ranks, item))
