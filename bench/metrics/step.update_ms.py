@@ -1,0 +1,10 @@
+"""Device time of the update (the program's ``update`` scope: grad-tier cast,
+clip and optimizer), in ms a traced step: the window's device time of
+every operation that ``repro.tracing.stage_of`` places there by its
+``op_name`` (``bench.scopes``), over the steps traced.  Nothing where the
+program names no stages."""
+from bench.scopes import stage_ms
+
+
+def read(record):
+    return stage_ms(record, "update")

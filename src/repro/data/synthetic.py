@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.tracing import span
+
 __all__ = ["lm_batch", "lm_eval_batch"]
 
 
@@ -38,25 +40,27 @@ def lm_batch(seed: int, step: int, batch: int, seq_len: int, vocab: int,
     """One global batch: {"tokens" (B,S), "labels" (B,S), "mask" (B,S)}.
 
     labels[t] = tokens[t+1] (next-token prediction); final position masked.
+    Runs under the host span ``data.lm_batch`` (``repro.tracing``).
     """
-    g = _rng(seed, step, stream)
-    succ = _markov_tables(seed, vocab)
-    branch = succ.shape[1]
-    toks = np.empty((batch, seq_len + 1), np.int32)
-    toks[:, 0] = g.integers(0, vocab, size=batch)
-    # 85% Markov successor, 15% uniform noise — learnable but not trivial.
-    choices = g.integers(0, branch, size=(batch, seq_len))
-    noise = g.integers(0, vocab, size=(batch, seq_len), dtype=np.int32)
-    take_noise = g.random((batch, seq_len)) < 0.15
-    for t in range(seq_len):
-        nxt = succ[toks[:, t], choices[:, t]]
-        toks[:, t + 1] = np.where(take_noise[:, t], noise[:, t], nxt)
-    mask = np.ones((batch, seq_len), np.float32)
-    return {
-        "tokens": toks[:, :seq_len],
-        "labels": toks[:, 1:],
-        "mask": mask,
-    }
+    with span("data.lm_batch"):
+        g = _rng(seed, step, stream)
+        succ = _markov_tables(seed, vocab)
+        branch = succ.shape[1]
+        toks = np.empty((batch, seq_len + 1), np.int32)
+        toks[:, 0] = g.integers(0, vocab, size=batch)
+        # 85% Markov successor, 15% uniform noise — learnable but not trivial.
+        choices = g.integers(0, branch, size=(batch, seq_len))
+        noise = g.integers(0, vocab, size=(batch, seq_len), dtype=np.int32)
+        take_noise = g.random((batch, seq_len)) < 0.15
+        for t in range(seq_len):
+            nxt = succ[toks[:, t], choices[:, t]]
+            toks[:, t + 1] = np.where(take_noise[:, t], noise[:, t], nxt)
+        mask = np.ones((batch, seq_len), np.float32)
+        return {
+            "tokens": toks[:, :seq_len],
+            "labels": toks[:, 1:],
+            "mask": mask,
+        }
 
 
 def lm_eval_batch(seed: int, step: int, batch: int, seq_len: int,

@@ -18,6 +18,7 @@ from jax.sharding import PartitionSpec
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models.transformer import cache_struct, forward, loss_fn
 from repro.optim.optimizers import Optimizer, clip_by_global_norm
+from repro.tracing import UPDATE
 
 __all__ = [
     "make_train_step", "make_ddp_train_step", "make_pipeline_train_step",
@@ -61,6 +62,22 @@ def _grads_at_rest(grads, cfg: ModelConfig):
     from repro.core import quant
 
     return jax.tree.map(lambda g: quant.cast_format(g, gfmt), grads)
+
+
+def _update(cfg: ModelConfig, opt: Optimizer, grads, params, opt_state,
+            clip_norm: float):
+    """Every step's tail after the gradients, under the ``update`` scope:
+    grad-tier cast, clip (or the bare norm) and the optimizer update.
+    Returns ``(params, opt_state, grad_norm)``."""
+    with jax.named_scope(UPDATE):
+        grads = _grads_at_rest(grads, cfg)
+        if clip_norm:
+            grads, gnorm = clip_by_global_norm(grads, clip_norm)
+        else:
+            gnorm = _global_grad_norm(grads)
+        params, opt_state = opt.update(grads, params, opt_state,
+                                       opt_state["step"])
+    return params, opt_state, gnorm
 
 
 def make_train_step(cfg: ModelConfig, opt: Optimizer, *, microbatches: int = 1,
@@ -183,13 +200,8 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, *, microbatches: int = 1,
 
     def train_step(params, opt_state, batch):
         loss, grads = loss_and_grads(params, batch)
-        grads = _grads_at_rest(grads, cfg)
-        if clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        else:
-            gnorm = _global_grad_norm(grads)
-        params, opt_state = opt.update(grads, params, opt_state,
-                                       opt_state["step"])
+        params, opt_state, gnorm = _update(cfg, opt, grads, params, opt_state,
+                                           clip_norm)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     return train_step
@@ -227,13 +239,8 @@ def make_ddp_train_step(cfg: ModelConfig, opt: Optimizer, mesh, *,
             grads = jax.tree.map(
                 lambda g: jax.lax.pmean(g, "data"), grads)
         loss = jax.lax.pmean(loss, "data")
-        grads = _grads_at_rest(grads, cfg)
-        if clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        else:
-            gnorm = _global_grad_norm(grads)
-        params, opt_state = opt.update(grads, params, opt_state,
-                                       opt_state["step"])
+        params, opt_state, gnorm = _update(cfg, opt, grads, params, opt_state,
+                                           clip_norm)
         return params, opt_state, ef, {"loss": loss, "grad_norm": gnorm}
 
     rep = P()
@@ -299,13 +306,8 @@ def make_pipeline_train_step(cfg: ModelConfig, opt: Optimizer, mesh, *,
     def step(params, opt_state, batch):
         loss, grads = pipeline_loss_and_grads(params, cfg, batch, part,
                                               remat=remat)
-        grads = _grads_at_rest(grads, cfg)
-        if clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-        else:
-            gnorm = _global_grad_norm(grads)
-        params, opt_state = opt.update(grads, params, opt_state,
-                                       opt_state["step"])
+        params, opt_state, gnorm = _update(cfg, opt, grads, params, opt_state,
+                                           clip_norm)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     rep = P()

@@ -45,6 +45,23 @@ from repro.runtime import (
     opt_state_specs,
     param_specs,
 )
+from repro.tracing import span
+
+
+class CompileCounter:
+    """Counts JAX's tracing, lowering and compile events (``n``) from the
+    moment it is made until ``close``."""
+
+    def __init__(self):
+        self.n = 0
+        jax.monitoring.register_event_duration_secs_listener(self)
+
+    def __call__(self, event: str, duration: float, **kwargs) -> None:
+        if event.startswith("/jax/core/compile/"):
+            self.n += 1
+
+    def close(self) -> None:
+        jax.monitoring.unregister_event_duration_listener(self)
 
 
 def build(args):
@@ -169,6 +186,11 @@ def main(argv=None) -> dict:
                          "split; TT cores replicated so fused kernels "
                          "stay fused)")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--trace-dir", default=None,
+                    help="profile steps start+2 .. start+11 with "
+                         "jax.profiler into this directory (open it in "
+                         "XProf: host spans train.* and data.*, device "
+                         "scopes embed/attn/ffn/head/update)")
     args = ap.parse_args(argv)
 
     cfg = build(args)
@@ -267,33 +289,51 @@ def main(argv=None) -> dict:
             print(f"[train] resumed from step {start}")
 
     monitor = StragglerMonitor()
+    compiles = CompileCounter()
     cadence = CheckpointCadence(base_interval=max(args.steps // 4, 1),
                                 min_interval=max(args.steps // 10, 1))
-    losses, grad_norms = [], []
+    trace_from = start + 2 if args.trace_dir else None
+    tracing = False
+    losses, grad_norms, recompiled = [], [], []
     next_ckpt = None
     for step in range(start, args.steps):
-        batch = {k: jnp.asarray(v) for k, v in
-                 lm_batch(args.seed, step, args.batch, args.seq, vocab).items()}
-        if bsh is not None:
-            batch = jax.tree.map(jax.device_put, batch, bsh)
-        t0 = time.time()
-        if guard is not None:
-            params, opt_state, metrics = step_fn(params, opt_state, batch,
-                                                 guard.controls())
-        else:
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
-        host = jax.device_get(metrics)  # the step's one device->host sync
+        if step == trace_from:
+            jax.profiler.start_trace(args.trace_dir)
+            tracing = True
+        compiles.n = 0
+        t0 = time.perf_counter()
+        with span("train.input"):
+            batch = {k: jnp.asarray(v) for k, v in
+                     lm_batch(args.seed, step, args.batch, args.seq,
+                              vocab).items()}
+            if bsh is not None:
+                batch = jax.tree.map(jax.device_put, batch, bsh)
+        with span("train.dispatch"):
+            if guard is not None:
+                params, opt_state, metrics = step_fn(
+                    params, opt_state, batch, guard.controls())
+            else:
+                params, opt_state, metrics = step_fn(params, opt_state, batch)
+        with span("train.sync"):
+            host = jax.device_get(metrics)  # the step's one device->host sync
         loss = float(host["loss"])
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         flagged = monitor.observe(dt)
         action = "ok"
         if guard is not None:
-            params, opt_state, action = guard.observe(step, metrics, params,
-                                                      opt_state)
+            with span("train.guard"):
+                params, opt_state, action = guard.observe(
+                    step, metrics, params, opt_state)
         losses.append(loss)
         grad_norms.append(float(host["grad_norm"]))
-        if step % args.log_every == 0 or step == args.steps - 1:
+        recompiled_now = compiles.n > 0 and step > start
+        if recompiled_now:
+            recompiled.append(step)
+        if (step % args.log_every == 0 or step == args.steps - 1
+                or recompiled_now):
             tag = "" if action == "ok" else f"  GUARD:{action.upper()}"
+            if recompiled_now:
+                tag += "  RECOMPILED"
             print(f"[train] step {step:5d} loss {loss:.4f} "
                   f"{dt*1e3:7.1f} ms{'  STRAGGLER' if flagged else ''}{tag}")
         if mgr is not None:
@@ -301,8 +341,15 @@ def main(argv=None) -> dict:
             if next_ckpt is None:
                 next_ckpt = step + interval
             if step + 1 >= next_ckpt or step == args.steps - 1:
-                mgr.save_async(step + 1, (params, opt_state))
+                with span("train.checkpoint"):
+                    mgr.save_async(step + 1, (params, opt_state))
                 next_ckpt = step + 1 + interval
+        if tracing and step == trace_from + 9:
+            jax.profiler.stop_trace()
+            tracing = False
+    if tracing:
+        jax.profiler.stop_trace()
+    compiles.close()
     if mgr is not None:
         mgr.wait()
     out = {"final_loss": losses[-1] if losses else None,
@@ -310,6 +357,8 @@ def main(argv=None) -> dict:
            "losses": losses,
            "grad_norms": grad_norms,
            "straggler_flags": monitor.total_flags,
+           # Steps after the first that traced, lowered or compiled.
+           "recompiled_steps": recompiled,
            # The config, the jitted step, the final state and the last
            # batch as placed, so a caller can check where they live and
            # inspect the program that ran.

@@ -37,6 +37,7 @@ from repro.core.meshctx import constrain as meshctx_constrain
 from repro.core.tt import ttm_reconstruct
 from repro.models.moe import moe_apply, moe_init
 from repro.models.ssm import mamba2_apply, mamba2_init, rglru_apply, rglru_init
+from repro.tracing import ATTN, EMBED, FFN, HEAD
 
 __all__ = [
     "init_params", "forward", "loss_fn", "lm_head", "token_nll",
@@ -149,15 +150,18 @@ def block_apply(kind: str, p: dict, x: jax.Array, cfg: ModelConfig, *,
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     if kind in ("attn", "attn_moe", "attn_local"):
         window = cfg.window if kind == "attn_local" else None
-        out, new_cache = _attn_apply(p["attn"], h, cfg, window=window,
-                                     cache=cache, mode=mode, pos=pos,
-                                     delta_cache=delta_cache)
+        with jax.named_scope(ATTN):
+            out, new_cache = _attn_apply(p["attn"], h, cfg, window=window,
+                                         cache=cache, mode=mode, pos=pos,
+                                         delta_cache=delta_cache)
         x = x + out
         h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
-        if kind == "attn_moe":
-            x = x + moe_apply(p["moe"], h2, cfg)
-        else:
-            x = x + mlp_apply(p["mlp"], h2, cfg)
+        with jax.named_scope(FFN):
+            if kind == "attn_moe":
+                out = moe_apply(p["moe"], h2, cfg)
+            else:
+                out = mlp_apply(p["mlp"], h2, cfg)
+        x = x + out
     elif kind == "ssm":
         out, new_cache = mamba2_apply(p["mixer"], h, cfg, cache, mode=mode)
         x = x + out
@@ -165,7 +169,9 @@ def block_apply(kind: str, p: dict, x: jax.Array, cfg: ModelConfig, *,
         out, new_cache = rglru_apply(p["mixer"], h, cfg, cache, mode=mode)
         x = x + out
         h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
-        x = x + mlp_apply(p["mlp"], h2, cfg)
+        with jax.named_scope(FFN):
+            out = mlp_apply(p["mlp"], h2, cfg)
+        x = x + out
     else:
         raise ValueError(kind)
     return x, new_cache
@@ -219,24 +225,25 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> dict:
 
 def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jax.Array,
                   patches: jax.Array | None, pos_offset) -> jax.Array:
-    h = embedding_apply(params["embed"], tokens)
-    if cfg.frontend == "patch" and patches is not None:
-        pe = linear_apply(params["patch_proj"], patches, flow=cfg.tt.flow,
-                          fused_bwd=cfg.tt.fused_bwd)
-        h = jnp.concatenate([pe, h[:, patches.shape[1]:, :]], axis=1)
-    if cfg.pos_embed == "learned":
-        S = tokens.shape[1]
-        idx = pos_offset + jnp.arange(S)
-        h = h + jnp.take(params["pos_table"], idx, axis=0)[None]
-    elif cfg.pos_embed == "sinusoidal":
-        S = tokens.shape[1]
-        d = cfg.d_model
-        pos = (pos_offset + jnp.arange(S))[:, None].astype(jnp.float32)
-        div = jnp.exp(jnp.arange(0, d, 2, jnp.float32) * (-jnp.log(10000.0) / d))
-        pe = jnp.zeros((S, d), jnp.float32)
-        pe = pe.at[:, 0::2].set(jnp.sin(pos * div)).at[:, 1::2].set(jnp.cos(pos * div))
-        h = h + pe.astype(h.dtype)[None]
-    return h
+    with jax.named_scope(EMBED):
+        h = embedding_apply(params["embed"], tokens)
+        if cfg.frontend == "patch" and patches is not None:
+            pe = linear_apply(params["patch_proj"], patches, flow=cfg.tt.flow,
+                              fused_bwd=cfg.tt.fused_bwd)
+            h = jnp.concatenate([pe, h[:, patches.shape[1]:, :]], axis=1)
+        if cfg.pos_embed == "learned":
+            S = tokens.shape[1]
+            idx = pos_offset + jnp.arange(S)
+            h = h + jnp.take(params["pos_table"], idx, axis=0)[None]
+        elif cfg.pos_embed == "sinusoidal":
+            S = tokens.shape[1]
+            d = cfg.d_model
+            pos = (pos_offset + jnp.arange(S))[:, None].astype(jnp.float32)
+            div = jnp.exp(jnp.arange(0, d, 2, jnp.float32) * (-jnp.log(10000.0) / d))
+            pe = jnp.zeros((S, d), jnp.float32)
+            pe = pe.at[:, 0::2].set(jnp.sin(pos * div)).at[:, 1::2].set(jnp.cos(pos * div))
+            h = h + pe.astype(h.dtype)[None]
+        return h
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
@@ -345,28 +352,29 @@ def lm_head(params: dict, cfg: ModelConfig, h: jax.Array) -> jax.Array:
     (runtime.pipeline), so the tied-TTM reconstruct path and the sharding
     constraints cannot diverge between the two.
     """
-    if cfg.tie_embeddings:
-        if isinstance(params["embed"], dict):
-            table = params["embed"]["table"]
+    with jax.named_scope(HEAD):
+        if cfg.tie_embeddings:
+            if isinstance(params["embed"], dict):
+                table = params["embed"]["table"]
+            else:
+                # Tied TTM head: materialize the table *transiently* (activation,
+                # not a stored param) — the build is O(V·H·r) FLOPs, negligible
+                # next to the logits GEMM, and shards on vocab under TP.
+                from repro.core.meshctx import constrain
+                emb = params["embed"]
+                table = constrain(
+                    ttm_reconstruct(emb.cores, emb.spec),
+                    "model", None)[: cfg.vocab_padded, : cfg.d_model].astype(h.dtype)
+            logits = jnp.einsum("bsd,vd->bsv", h, table,
+                                preferred_element_type=jnp.float32).astype(h.dtype)
         else:
-            # Tied TTM head: materialize the table *transiently* (activation,
-            # not a stored param) — the build is O(V·H·r) FLOPs, negligible
-            # next to the logits GEMM, and shards on vocab under TP.
-            from repro.core.meshctx import constrain
-            emb = params["embed"]
-            table = constrain(
-                ttm_reconstruct(emb.cores, emb.spec),
-                "model", None)[: cfg.vocab_padded, : cfg.d_model].astype(h.dtype)
-        logits = jnp.einsum("bsd,vd->bsv", h, table,
-                            preferred_element_type=jnp.float32).astype(h.dtype)
-    else:
-        logits = linear_apply(params["head"], h, flow=cfg.tt.flow,
-                              fused_bwd=cfg.tt.fused_bwd)
-    # Vocab-shard the logits explicitly: with a TT head the weight factors
-    # are replicated, so GSPMD has no lineage to shard the (B, S, V) output
-    # — unconstrained it replicates ~40 GB/device of logits on 150k-vocab
-    # archs (EXPERIMENTS.md §Perf, technique cell iteration).
-    return meshctx_constrain(logits, ("pod", "data"), None, "model")
+            logits = linear_apply(params["head"], h, flow=cfg.tt.flow,
+                                  fused_bwd=cfg.tt.fused_bwd)
+        # Vocab-shard the logits explicitly: with a TT head the weight factors
+        # are replicated, so GSPMD has no lineage to shard the (B, S, V) output
+        # — unconstrained it replicates ~40 GB/device of logits on 150k-vocab
+        # archs (EXPERIMENTS.md §Perf, technique cell iteration).
+        return meshctx_constrain(logits, ("pod", "data"), None, "model")
 
 
 def token_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -378,11 +386,12 @@ def token_nll(logits: jax.Array, labels: jax.Array) -> jax.Array:
     logits — the masked sum keeps everything local + one scalar-per-token
     all-reduce.
     """
-    logits = logits.astype(jnp.float32)
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-    gold = jnp.sum(jnp.where(vocab_iota == labels[..., None], logits, 0.0), axis=-1)
-    return logz - gold
+    with jax.named_scope(HEAD):
+        logits = logits.astype(jnp.float32)
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+        gold = jnp.sum(jnp.where(vocab_iota == labels[..., None], logits, 0.0), axis=-1)
+        return logz - gold
 
 
 def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *, remat: bool = True):

@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.runtime.straggler import StragglerMonitor
+from repro.tracing import UPDATE
 
 __all__ = ["GuardPolicy", "TrainGuard", "guard_controls",
            "apply_guarded_update", "make_guarded_step"]
@@ -94,7 +95,8 @@ def apply_guarded_update(opt, loss, grads, params, opt_state, ctrl, *,
     sentinel), the single fused norm/finite reduction, global-norm
     clipping, ``opt.update``, and the skip-step select.  Returns
     ``(params, opt_state, metrics)`` with metrics
-    ``{loss, grad_norm, nonfinite, sat_frac, applied}``.
+    ``{loss, grad_norm, nonfinite, sat_frac, applied}``.  All of it runs
+    under the ``update`` scope (``repro.tracing``).
     """
     from repro.core import quant
 
@@ -103,63 +105,64 @@ def apply_guarded_update(opt, loss, grads, params, opt_state, ctrl, *,
                          "dynamic range collapses under a per-tensor "
                          "scale; use 'bfloat16' or 'fp8_e5m2'")
 
-    # Chaos injection: additive into ONE element of the first leaf.
-    # Additive (not multiplicative) on purpose — a scaled tier rescales a
-    # uniform multiply away, but a single huge outlier is exactly the
-    # shape that blows up a per-tensor max-abs scale.
-    leaves, tdef = jax.tree.flatten(grads)
-    first = leaves[0].reshape(-1)
-    first = first.at[0].add(ctrl["fault_add"].astype(first.dtype))
-    leaves[0] = first.reshape(leaves[0].shape)
-    grads = jax.tree.unflatten(tdef, leaves)
+    with jax.named_scope(UPDATE):
+        # Chaos injection: additive into ONE element of the first leaf.
+        # Additive (not multiplicative) on purpose — a scaled tier rescales a
+        # uniform multiply away, but a single huge outlier is exactly the
+        # shape that blows up a per-tensor max-abs scale.
+        leaves, tdef = jax.tree.flatten(grads)
+        first = leaves[0].reshape(-1)
+        first = first.at[0].add(ctrl["fault_add"].astype(first.dtype))
+        leaves[0] = first.reshape(leaves[0].shape)
+        grads = jax.tree.unflatten(tdef, leaves)
 
-    # Grad tier: both casts live in the graph; grad_bf16 selects at run
-    # time (elementwise where on a () predicate — no retrace, no branch).
-    if grad_fmt == "float32":
-        sat_frac = jnp.float32(0.0)
-    elif quant.needs_scale(grad_fmt):
-        lo = jax.tree.map(lambda g: quant.cast_format(g, grad_fmt), grads)
-        hi = jax.tree.map(lambda g: quant.cast_format(g, "bfloat16"), grads)
-        fracs = [quant.lost_fraction(g, l) for g, l in
-                 zip(jax.tree.leaves(grads), jax.tree.leaves(lo))]
-        sat_frac = jnp.max(jnp.stack(fracs))
-        esc = ctrl["grad_bf16"]
-        grads = jax.tree.map(lambda l, h: jnp.where(esc, h, l), lo, hi)
-    else:  # bfloat16: cast-only round trip, nothing to escalate to
-        sat_frac = jnp.float32(0.0)
-        grads = jax.tree.map(lambda g: quant.cast_format(g, grad_fmt), grads)
+        # Grad tier: both casts live in the graph; grad_bf16 selects at run
+        # time (elementwise where on a () predicate — no retrace, no branch).
+        if grad_fmt == "float32":
+            sat_frac = jnp.float32(0.0)
+        elif quant.needs_scale(grad_fmt):
+            lo = jax.tree.map(lambda g: quant.cast_format(g, grad_fmt), grads)
+            hi = jax.tree.map(lambda g: quant.cast_format(g, "bfloat16"), grads)
+            fracs = [quant.lost_fraction(g, l) for g, l in
+                     zip(jax.tree.leaves(grads), jax.tree.leaves(lo))]
+            sat_frac = jnp.max(jnp.stack(fracs))
+            esc = ctrl["grad_bf16"]
+            grads = jax.tree.map(lambda l, h: jnp.where(esc, h, l), lo, hi)
+        else:  # bfloat16: cast-only round trip, nothing to escalate to
+            sat_frac = jnp.float32(0.0)
+            grads = jax.tree.map(lambda g: quant.cast_format(g, grad_fmt), grads)
 
-    # ONE reduction: grad norm == finite probe == clip denominator.
-    sumsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grads))
-    gnorm = jnp.sqrt(sumsq)
-    finite = jnp.isfinite(gnorm) & jnp.isfinite(loss)
-    ok = finite | jnp.logical_not(ctrl["guard_on"])
+        # ONE reduction: grad norm == finite probe == clip denominator.
+        sumsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(grads))
+        gnorm = jnp.sqrt(sumsq)
+        finite = jnp.isfinite(gnorm) & jnp.isfinite(loss)
+        ok = finite | jnp.logical_not(ctrl["guard_on"])
 
-    if clip_norm:
-        cscale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-9))
-        grads = jax.tree.map(
-            lambda g: (g.astype(jnp.float32) * cscale).astype(g.dtype),
-            grads)
+        if clip_norm:
+            cscale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-9))
+            grads = jax.tree.map(
+                lambda g: (g.astype(jnp.float32) * cscale).astype(g.dtype),
+                grads)
 
-    new_params, new_state = opt.update(grads, params, opt_state,
-                                       opt_state["step"])
-    # Skip-step: masked select on params AND the full state tree.  Old
-    # and new leaves agree in shape/dtype for every layout (dense m/v,
-    # sketched vs/ms, quantized pq/ps, lr_scale), so one tree.map keeps
-    # the whole optimizer consistent on a skipped step — including NOT
-    # advancing the bias-correction step counter.
-    sel = lambda n, o: jnp.where(ok, n, o)
-    params = jax.tree.map(sel, new_params, params)
-    opt_state = jax.tree.map(sel, new_state, opt_state)
-    metrics = {
-        "loss": loss,
-        "grad_norm": gnorm,
-        "nonfinite": 1.0 - finite.astype(jnp.float32),
-        "sat_frac": sat_frac,
-        "applied": ok.astype(jnp.float32),
-    }
-    return params, opt_state, metrics
+        new_params, new_state = opt.update(grads, params, opt_state,
+                                           opt_state["step"])
+        # Skip-step: masked select on params AND the full state tree.  Old
+        # and new leaves agree in shape/dtype for every layout (dense m/v,
+        # sketched vs/ms, quantized pq/ps, lr_scale), so one tree.map keeps
+        # the whole optimizer consistent on a skipped step — including NOT
+        # advancing the bias-correction step counter.
+        sel = lambda n, o: jnp.where(ok, n, o)
+        params = jax.tree.map(sel, new_params, params)
+        opt_state = jax.tree.map(sel, new_state, opt_state)
+        metrics = {
+            "loss": loss,
+            "grad_norm": gnorm,
+            "nonfinite": 1.0 - finite.astype(jnp.float32),
+            "sat_frac": sat_frac,
+            "applied": ok.astype(jnp.float32),
+        }
+        return params, opt_state, metrics
 
 
 def make_guarded_step(loss_of: Callable[[Any, Any], jax.Array], opt, *,
