@@ -180,14 +180,18 @@ def _pu_kernel_vmem_bytes(n_params: int, n_bufs: int) -> int:
     return n_bufs * br * lanes * 4
 
 
-def _attn_kernel_vmem_bytes(cfg, seq: int, itemsize: int, stage: str) -> int:
-    """VMEM working set of the attention-stage flash launch — derived from
-    the BACKWARD kernel's own tile chooser (``choose_attn_tiles``), so
-    ledger and launched tiles cannot drift; 0 when ``fused_attn`` is off or
-    the shape falls back to the pure-JAX blockwise path."""
+def _attn_kernel_vmem_bytes(cfg, batch: int, seq: int, itemsize: int,
+                            stage: str) -> int:
+    """VMEM working set of the attention-stage flash launch over ``batch``
+    sequences — derived from the BACKWARD kernel's own tile chooser
+    (``choose_attn_tiles``, head block included), so ledger and launched
+    blocks cannot drift; 0 when ``fused_attn`` is off or the shape falls
+    back to the pure-JAX blockwise path."""
     from repro.kernels.flash_backward import attn_stage_vmem_bytes
 
     return attn_stage_vmem_bytes(seq, cfg.d_head, itemsize,
+                                 rows=batch * cfg.n_heads,
+                                 group=cfg.n_heads // cfg.n_kv_heads,
                                  stage=stage, fused=cfg.fused_attn)
 
 
@@ -466,8 +470,10 @@ def training_step_ledger(cfg, optimizer: str = "sgd", *, momentum: float = 0.0,
         (_btt_bwd_kernel_vmem_bytes(s, act_itemsize, K, cfg.tt.fused_bwd)
          for s in specs),
         default=0)
-    attn_fwd_vmem = _attn_kernel_vmem_bytes(cfg, seq, act_itemsize, "FWD")
-    attn_bwd_vmem = _attn_kernel_vmem_bytes(cfg, seq, act_itemsize, "BWD")
+    attn_fwd_vmem = _attn_kernel_vmem_bytes(cfg, b_mb, seq, act_itemsize,
+                                            "FWD")
+    attn_bwd_vmem = _attn_kernel_vmem_bytes(cfg, b_mb, seq, act_itemsize,
+                                            "BWD")
     # Live VMEM blocks per fused_update grid step = the input buffer list
     # (outputs are aliased onto inputs): (p, g) / (p, mu, g) / (p, m, v, g).
     # On the sketched path the working set comes from the sketched kernel's

@@ -14,6 +14,15 @@ materializing repeated KV: the K/V BlockSpec index maps query-head ``h`` to
 its KV head ``h // group`` — the repeat happens in the index computation,
 not in memory.  Fully-masked causal blocks are skipped via ``pl.when``.
 
+Short sequences take a head block instead (``hb > 1``, chosen by
+``flash_backward.choose_attn_tiles`` when the whole sequence is one tile):
+grid = (B·H/hb,), and one step takes ``hb`` whole (batch·head) rows — q and
+o blocks ``(hb, S, D)``, k/v blocks ``(hb/group, S, D)`` of the KV heads
+those query heads share, statistics ``(hb, S)`` — at their own S and D, so
+nothing is padded and no online-softmax state is carried.  At S = 32 a
+(batch, head) pair is a few KB of data, and as a grid step of its own it
+costs the step's fixed overhead; a head block amortizes that overhead.
+
 ``return_residuals=True`` additionally emits the per-row softmax statistics
 ``(m, l)`` — the residuals the fused backward (``flash_backward.py``)
 recomputes probability tiles from, so training never saves the S×S
@@ -101,13 +110,102 @@ def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
 
+# ---------------------------------------------------------------------------
+# Head-block path: ``hb`` whole (batch·head) rows a grid step.
+# ---------------------------------------------------------------------------
+
+
+def rows_mask(shape: tuple[int, int, int], causal: bool,
+              window: int | None):
+    """Keep-mask of an ``(hb, S, S)`` score block, None when all is kept."""
+    if not causal and window is None:
+        return None
+    qpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    mask = kpos <= qpos if causal else jnp.ones(shape, bool)
+    if window is not None:
+        mask &= kpos > qpos - window
+    return mask
+
+
+def repeat_heads(x: jax.Array, group: int) -> jax.Array:
+    """``(n, S, D) -> (n·group, S, D)``: each KV head once per query head
+    of its group, in the query heads' order (``h -> h // group``)."""
+    if group == 1:
+        return x
+    n, S, D = x.shape
+    return jnp.broadcast_to(x[:, None], (n, group, S, D)).reshape(
+        n * group, S, D)
+
+
+def batched_dot(a, b, contract: tuple[int, int]):
+    """Batched f32-accumulated product over the leading (head) axis."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+
+def _rows_kernel(q_ref, k_ref, v_ref, *refs, scale: float, causal: bool,
+                 window: int | None, group: int, emit_stats: bool):
+    """One grid step of the head-block path: the whole key row of ``hb``
+    query heads, so the softmax is exact in one pass.  The same products
+    and f32 softmax as ``_kernel`` at ``nk = 1``."""
+    q = q_ref[...]                                # (hb, S, D)
+    k = repeat_heads(k_ref[...], group)
+    v = repeat_heads(v_ref[...], group)
+    s = batched_dot(q, k, (2, 2)) * scale         # (hb, S, S) f32
+    mask = rows_mask(s.shape, causal, window)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    m = s.max(axis=2, keepdims=True)              # (hb, S, 1)
+    p = jnp.exp(s - m)
+    l = p.sum(axis=2, keepdims=True)
+    acc = batched_dot(p.astype(v.dtype), v, (2, 1))
+    refs[0][...] = (acc / jnp.maximum(l, 1e-30)).astype(refs[0].dtype)
+    if emit_stats:
+        refs[1][...] = m[..., 0]                  # (hb, S): S on lanes
+        refs[2][...] = l[..., 0]
+
+
+def _flash_rows(q, k, v, *, causal, window, group, hb, interpret,
+                return_residuals):
+    BH, S, D = q.shape
+    if BH % hb or hb % group:
+        raise ValueError(f"head block {hb} must divide {BH} rows and be a "
+                         f"multiple of the group {group}")
+
+    def block(n):
+        return pl.BlockSpec((n, S, D), lambda i: (i, 0, 0))
+
+    out_specs = [block(hb)]
+    out_shape = [jax.ShapeDtypeStruct((BH, S, D), q.dtype)]
+    if return_residuals:
+        stat = pl.BlockSpec((hb, S), lambda i: (i, 0))
+        out_specs += [stat, stat]
+        out_shape += [jax.ShapeDtypeStruct((BH, S), jnp.float32)] * 2
+    res = pl.pallas_call(
+        functools.partial(_rows_kernel, scale=1.0 / math.sqrt(D),
+                          causal=causal, window=window, group=group,
+                          emit_stats=return_residuals),
+        name="flash_fwd",
+        grid=(BH // hb,),
+        in_specs=[block(hb), block(hb // group), block(hb // group)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(q, k, v)
+    return tuple(res) if return_residuals else res[0]
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "group", "tq", "tk", "interpret",
+    "causal", "window", "group", "tq", "tk", "hb", "interpret",
     "return_residuals"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int | None = None,
                            group: int = 1, tq: int | None = None,
-                           tk: int | None = None,
+                           tk: int | None = None, hb: int = 1,
                            interpret: bool = False,
                            return_residuals: bool = False):
     """``q (BH, S, D); k, v (BH/group, S, D) -> o (BH, S, D)``.
@@ -117,10 +215,18 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     S is padded to the tile grid; padded KV columns are masked, padded Q
     rows sliced off.
 
+    ``hb > 1`` takes the head-block path (module docstring): ``hb`` rows a
+    grid step, each over its whole sequence; ``hb`` divides BH and is a
+    multiple of ``group``, and ``tq``/``tk`` are unused.
+
     ``return_residuals=True`` returns ``(o, m, l)`` with ``m, l (BH, S)``
     f32 — the per-row softmax max / normalizer the fused backward kernel
     needs to recompute probability tiles without the S×S matrix.
     """
+    if hb > 1:
+        return _flash_rows(q, k, v, causal=causal, window=window,
+                           group=group, hb=hb, interpret=interpret,
+                           return_residuals=return_residuals)
     BH, S, D = q.shape
     scale = 1.0 / math.sqrt(D)
     tq = tq or min(DEFAULT_TQ, _round_up(S, 128))

@@ -54,6 +54,14 @@ Tiles go down to 32 rows (the f32 sublane granule) so the paper's S=32
 training regime launches without sequence padding; sub-128 lane tiles are
 legal for the (1, T, D) blocks (T is a sublane dim there) and Mosaic pads
 the (TQ, TK) score-tile lanes in-register.
+
+Where the whole sequence is one tile, the chooser also sets a head block
+``hb > 1`` (``flash_attention.py``'s module docstring): grid = (B·H/hb,),
+q/do/o/dq blocks ``(hb, S, D)``, k/v/dk/dv blocks ``(hb/G, S, D)``, m/l
+blocks ``(hb, S)``, all at the arrays' own S and D.  A step holds every
+query head of its KV heads, so dK/dV are summed over the group inside the
+step (group members ascending, as the reference) and written once; nothing
+accumulates across steps, and the gradients leave in the operands' dtype.
 """
 from __future__ import annotations
 
@@ -66,7 +74,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .btt_linear import VMEM_BUDGET, _round_up
-from .flash_attention import DEFAULT_TK, DEFAULT_TQ, NEG_INF
+from .flash_attention import (
+    DEFAULT_TK,
+    DEFAULT_TQ,
+    NEG_INF,
+    batched_dot,
+    repeat_heads,
+    rows_mask,
+)
 
 __all__ = [
     "flash_attention_bwd_pallas",
@@ -87,11 +102,31 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 
-def choose_attn_tiles(S: int, D: int, itemsize: int, *,
-                      tq: int | None = None, tk: int | None = None,
-                      budget: int | None = None
-                      ) -> tuple[int, int, int, int, int]:
-    """(tq, tk, sp, dp, vmem_bytes) for the fused attention backward.
+def _rows_vmem(hb: int, S: int, D: int, itemsize: int, group: int,
+               stage: str) -> int:
+    """VMEM working set of one head-block grid step: every block
+    double-buffered, plus the f32 (S, S) score-shaped temporaries and f32
+    products of ``hb`` heads.  Sizes are the VMEM tiles': S rounded to 32
+    sublanes, D and the key axis to 128 lanes."""
+    sp, dp, kp = _round_up(S, 32), _round_up(D, 128), _round_up(S, 128)
+    blk, stat, kv = sp * dp * itemsize, kp * 4, hb // group
+    if stage == "FWD":
+        # q, k, v in; o, m, l out; the s/p score tiles and the f32 acc.
+        io = (2 * hb + 2 * kv) * blk + 2 * hb * stat
+        tmp = hb * (2 * sp * kp + sp * dp) * 4
+    else:
+        # q, do, o, m, l, k, v in; dq, dk, dv out; the s/p, dp, ds score
+        # tiles and the f32 dq, dk, dv.
+        io = (4 * hb + 4 * kv) * blk + 2 * hb * stat
+        tmp = hb * (3 * sp * kp + 3 * sp * dp) * 4
+    return 2 * io + tmp
+
+
+def choose_attn_tiles(S: int, D: int, itemsize: int, *, rows: int = 1,
+                      group: int = 1, tq: int | None = None,
+                      tk: int | None = None, budget: int | None = None
+                      ) -> tuple[int, int, int, int, int, int]:
+    """(hb, tq, tk, sp, dp, vmem_bytes) for the fused attention backward.
 
     Tiles start at ``min(256, round_up(S, 32))`` — the 32-row granule keeps
     the paper's S=32 regime unpadded on the sequence axis — and the larger
@@ -100,6 +135,14 @@ def choose_attn_tiles(S: int, D: int, itemsize: int, *,
     never fit: callers gate on :func:`attn_bwd_vmem_fits` and fall back to
     the pure-JAX blockwise path.  (The per-step working set is independent
     of the GQA group size — the group only multiplies the grid.)
+
+    ``hb`` is the head block, the (batch·head) rows one grid step takes.
+    Where the whole sequence is one tile (``nq = nk = 1``) it is the
+    largest count of the ``rows`` (B·H) that divides them, is a multiple of
+    ``group`` and of 8 (the statistics' sublane tile) or all of them, and
+    whose double-buffered working set fits the budget; ``vmem_bytes`` is
+    then that working set.  Otherwise, or where no such count beats 1,
+    ``hb = 1``: one (batch·head) pair a step, as the tiles say.
     """
     budget = budget or VMEM_BUDGET
     tq = tq or min(DEFAULT_TQ, _round_up(S, 32))
@@ -126,27 +169,38 @@ def choose_attn_tiles(S: int, D: int, itemsize: int, *,
         # non-dividing tile would silently drop tail blocks from the grid.
         raise ValueError(
             f"tiles ({tq}, {tk}) do not both divide padded S={sp}")
-    return tq, tk, sp, dp, vmem(tq, tk)
+    if sp == tq == tk:
+        for hb in range(rows, 1, -1):
+            if (rows % hb == 0 and hb % group == 0
+                    and (hb % 8 == 0 or hb == rows)):
+                fit = _rows_vmem(hb, S, D, itemsize, group, "BWD")
+                if fit <= budget:
+                    return hb, tq, tk, sp, dp, fit
+    return 1, tq, tk, sp, dp, vmem(tq, tk)
 
 
 def attn_bwd_vmem_fits(S: int, D: int, itemsize: int, *,
                        budget: int | None = None) -> bool:
     """True iff the fused attention BWD working set fits the VMEM budget."""
     budget = budget or VMEM_BUDGET
-    return choose_attn_tiles(S, D, itemsize, budget=budget)[4] <= budget
+    return choose_attn_tiles(S, D, itemsize, budget=budget)[5] <= budget
 
 
-def attn_stage_vmem_bytes(S: int, D: int, itemsize: int, *,
-                          stage: str = "BWD", fused: bool = True,
+def attn_stage_vmem_bytes(S: int, D: int, itemsize: int, *, rows: int = 1,
+                          group: int = 1, stage: str = "BWD",
+                          fused: bool = True,
                           budget: int | None = None) -> int:
     """VMEM working set the attention stage ACTUALLY launches: the fused
     kernel's (backward-chooser-derived) when ``fused`` and it fits, else 0
     (the fallback is the pure-JAX blockwise path — no Pallas launch).
+    ``rows``/``group`` (B·H and the GQA group) set the head block.
     ``core.memory_ledger`` reports exactly this number per stage."""
     if not fused or not attn_bwd_vmem_fits(S, D, itemsize, budget=budget):
         return 0
-    tq, tk, sp, dp, bwd_vmem = choose_attn_tiles(S, D, itemsize,
-                                                 budget=budget)
+    hb, tq, tk, sp, dp, bwd_vmem = choose_attn_tiles(
+        S, D, itemsize, rows=rows, group=group, budget=budget)
+    if hb > 1:
+        return _rows_vmem(hb, S, D, itemsize, group, stage)
     if stage == "BWD":
         return bwd_vmem
     # FWD: q + k + v + o blocks, m/l/acc scratch, one (tq, tk) score tile.
@@ -249,28 +303,107 @@ def _bwd_kernel(q_ref, do_ref, o_ref, m_ref, l_ref, k_ref, v_ref,
             preferred_element_type=jnp.float32)
 
 
+def _rows_bwd_kernel(q_ref, do_ref, o_ref, m_ref, l_ref, k_ref, v_ref,
+                     dq_ref, dk_ref, dv_ref, *, scale: float, causal: bool,
+                     window: int | None, group: int):
+    """One grid step of the head-block path: the ``_bwd_kernel`` step's
+    products for ``hb`` whole heads at once (``nq = nk = 1``)."""
+    f32, bdot = jnp.float32, batched_dot
+    q = q_ref[...].astype(f32)                        # (hb, S, D)
+    do = do_ref[...].astype(f32)
+    o = o_ref[...].astype(f32)
+    k = repeat_heads(k_ref[...].astype(f32), group)   # (hb, S, D)
+    v = repeat_heads(v_ref[...].astype(f32), group)
+    m = m_ref[...][..., None]                         # (hb, S, 1) f32
+    l = l_ref[...][..., None]
+
+    s = bdot(q * scale, k, (2, 2))                    # (hb, S, S)
+    mask = rows_mask(s.shape, causal, window)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    p = jnp.exp(s - m) / jnp.maximum(l, 1e-30)
+    dv = bdot(p, do, (1, 1))
+    dp_ = bdot(do, v, (2, 2))
+    d_row = jnp.sum(do * o, axis=2, keepdims=True)
+    ds = p * (dp_ - d_row) * scale
+    dq_ref[...] = bdot(ds, k, (2, 1)).astype(dq_ref.dtype)
+    dk = bdot(ds, q, (1, 1))
+
+    def group_sum(x):
+        # Members of one KV head are adjacent rows; add them ascending.
+        if group == 1:
+            return x
+        x = x.reshape(x.shape[0] // group, group, *x.shape[1:])
+        acc = x[:, 0]
+        for g in range(1, group):
+            acc = acc + x[:, g]
+        return acc
+
+    dk_ref[...] = group_sum(dk).astype(dk_ref.dtype)
+    dv_ref[...] = group_sum(dv).astype(dv_ref.dtype)
+
+
+def _flash_rows_bwd(q, k, v, o, m, l, do, *, causal, window, group, hb,
+                    interpret):
+    BH, S, D = q.shape
+    BKV = k.shape[0]
+    if BH % hb or hb % group:
+        raise ValueError(f"head block {hb} must divide {BH} rows and be a "
+                         f"multiple of the group {group}")
+
+    def block(n):
+        return pl.BlockSpec((n, S, D), lambda i: (i, 0, 0))
+
+    stat = pl.BlockSpec((hb, S), lambda i: (i, 0))
+    kv = hb // group
+    return tuple(pl.pallas_call(
+        functools.partial(_rows_bwd_kernel, scale=1.0 / math.sqrt(D),
+                          causal=causal, window=window, group=group),
+        name="flash_bwd",
+        grid=(BH // hb,),
+        in_specs=[block(hb), block(hb), block(hb), stat, stat,
+                  block(kv), block(kv)],
+        out_specs=[block(hb), block(kv), block(kv)],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+            jax.ShapeDtypeStruct((BKV, S, D), k.dtype),
+            jax.ShapeDtypeStruct((BKV, S, D), v.dtype),
+        ],
+        # Each step owns its heads' whole gradients: no state crosses steps.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(q, do, o, m.astype(jnp.float32), l.astype(jnp.float32), k, v))
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "group", "tq", "tk", "interpret"))
+    "causal", "window", "group", "tq", "tk", "hb", "interpret"))
 def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                                o: jax.Array, m: jax.Array, l: jax.Array,
                                do: jax.Array, *, causal: bool = True,
                                window: int | None = None, group: int = 1,
                                tq: int | None = None, tk: int | None = None,
-                               interpret: bool = False
+                               hb: int = 1, interpret: bool = False
                                ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused BWD stage: ``(dq (BH,S,D), dk, dv (BH/group,S,D))``.
 
     ``q/o/do (BH, S, D)``, ``m/l (BH, S)`` f32 (the forward's residuals),
     ``k/v (BH/group, S, D)``.  All dims padded to the chooser's tiles;
     padded Q rows carry ``do = 0`` so every padded contribution vanishes
-    exactly.  ``interpret=True`` runs the kernel body in Python on CPU —
-    the validation path, as for every kernel in this package.
+    exactly.  ``hb > 1`` takes the head-block path (module docstring),
+    unpadded, with ``tq``/``tk`` unused.  ``interpret=True`` runs the
+    kernel body in Python on CPU — the validation path, as for every
+    kernel in this package.
     """
+    if hb > 1:
+        return _flash_rows_bwd(q, k, v, o, m, l, do, causal=causal,
+                               window=window, group=group, hb=hb,
+                               interpret=interpret)
     BH, S, D = q.shape
     BKV = k.shape[0]
     scale = 1.0 / math.sqrt(D)
     itemsize = jnp.dtype(q.dtype).itemsize
-    tq, tk, sp, dp, _ = choose_attn_tiles(S, D, itemsize, tq=tq, tk=tk)
+    _, tq, tk, sp, dp, _ = choose_attn_tiles(S, D, itemsize, tq=tq, tk=tk)
 
     def pad3(x):
         return jnp.pad(x, ((0, 0), (0, sp - S), (0, dp - x.shape[2])))
@@ -364,11 +497,21 @@ def fused_attn_hbm_bytes(B: int, H: int, KV: int, S: int, D: int,
     Backward: q/do/o/m/l read once per ``t`` (their index is constant
     across the inner KV axis), k/v refetched per step, dq written once per
     Q block, dk/dv flushed once per KV head.  No S×S tensor appears on
-    either side.  Padded bytes are real bytes on the wire.
+    either side.  Padded bytes are real bytes on the wire.  With a head
+    block (``hb > 1``, batch ``B`` sets it) nothing is padded or refetched.
     """
-    tq, tk, sp, dp, _ = choose_attn_tiles(S, D, itemsize)
-    nq, nk = sp // tq, sp // tk
     BH, BKV = B * H, B * KV
+    hb, tq, tk, sp, dp, _ = choose_attn_tiles(S, D, itemsize, rows=BH,
+                                              group=H // KV)
+    if hb > 1:
+        # Head blocks: every operand read once and every result written
+        # once, unpadded; the gradients leave in the operands' dtype.
+        qb = BH * S * D * itemsize                  # one q-shaped tensor
+        kvb = BKV * S * D * itemsize                # one k-shaped tensor
+        stats = 2 * BH * S * 4                      # m, l
+        return (2 * qb + 2 * kvb + stats            # fwd: q, k, v, o, m, l
+                + 4 * qb + 4 * kvb + stats)         # bwd: + do, dq, dk, dv
+    nq, nk = sp // tq, sp // tk
     fwd = (BH * sp * dp * itemsize                  # q read once
            + BH * nq * nk * 2 * tk * dp * itemsize  # k/v refetched
            + BH * sp * dp * itemsize                # o written

@@ -35,9 +35,11 @@
 * ``flash_mha_op(q, k, v)`` — training/prefill attention as the fused flash
   kernels: forward saves only ``(O, m, l)`` per layer; the backward is ONE
   ``pallas_call`` (``flash_backward.py``) recomputing probability tiles in
-  VMEM — no S×S tensor is ever saved or moved.  Shapes whose backward
-  working set exceeds the VMEM budget (dK/dV residency grows with S) fall
-  back to the pure-JAX ``blockwise_attention`` under plain autodiff.
+  VMEM — no S×S tensor is ever saved or moved.  Where the whole sequence
+  is one tile, both launches take head blocks of whole sequences.  Shapes
+  whose backward working set exceeds the VMEM budget (dK/dV residency
+  grows with S) fall back to the pure-JAX ``blockwise_attention`` under
+  plain autodiff.
 
 Kernel selection: on a TPU backend the compiled kernel runs natively; on CPU
 (this container) ``interpret=True`` executes the kernel body in Python — the
@@ -58,6 +60,7 @@ pre-precision kernels.
 """
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 
 import jax
@@ -67,6 +70,7 @@ import numpy as np
 from repro.core import quant as _quant
 from repro.core.contraction import tt_forward_btt, ttm_lookup, token_digits
 from repro.core.tt import TTMSpec, TTSpec, tt_half_factors
+from repro.tracing import FLASH_ROWS
 
 from .btt_backward import btt_backward_pallas, bwd_vmem_fits
 from .btt_ffn import (
@@ -427,19 +431,33 @@ def _flash_fused(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
     return o
 
 
+def _flash_tiles(q, group, budget):
+    """``(hb, tq, tk)`` and the scope the launches run under.
+
+    One tile choice (under the caller's budget) feeds BOTH launches, so
+    the gate, the forward, and the backward agree on the working set.  A
+    head block (``hb > 1``) runs under ``tracing.FLASH_ROWS``, so a trace's
+    ``op_name`` shows which flash executions took it."""
+    BH, S, D = q.shape
+    hb, tq, tk, _, _, _ = choose_attn_tiles(
+        S, D, jnp.dtype(q.dtype).itemsize, rows=BH, group=group,
+        budget=budget)
+    scope = (jax.named_scope(FLASH_ROWS) if hb > 1
+             else contextlib.nullcontext())
+    return (hb, tq, tk), scope
+
+
 def _flash_fwd_call(q, k, v, causal, window, group, interpret, budget):
-    # One tile choice (under the caller's budget) feeds BOTH launches, so
-    # the gate, the forward, and the backward agree on the working set.
     # The (m, l) statistics are per-row and tile-independent; the
     # backward's recomputed probabilities track the forward's to an ulp
     # (its score dot folds the softmax scale into Q — see
     # flash_backward._bwd_kernel), which the oracle tolerances absorb.
-    itemsize = jnp.dtype(q.dtype).itemsize
-    tq, tk, _, _, _ = choose_attn_tiles(q.shape[1], q.shape[2], itemsize,
-                                        budget=budget)
-    return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  group=group, tq=tq, tk=tk,
-                                  interpret=interpret, return_residuals=True)
+    (hb, tq, tk), scope = _flash_tiles(q, group, budget)
+    with scope:
+        return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                      group=group, tq=tq, tk=tk, hb=hb,
+                                      interpret=interpret,
+                                      return_residuals=True)
 
 
 def _flash_fused_fwd(q, k, v, causal, window, group, interpret, budget,
@@ -471,13 +489,11 @@ def _flash_fused_bwd(causal, window, group, interpret, budget, afmt,
         k = _deq(k, scales[1], cdt)
         v = _deq(v, scales[2], cdt)
         o = _deq(o, scales[3], cdt)
-    itemsize = jnp.dtype(q.dtype).itemsize
-    tq, tk, _, _, _ = choose_attn_tiles(q.shape[1], q.shape[2], itemsize,
-                                        budget=budget)
-    dq, dk, dv = flash_attention_bwd_pallas(
-        q, k, v, o, m, l, do, causal=causal, window=window, group=group,
-        tq=tq, tk=tk, interpret=interpret)
-    return dq, dk, dv
+    (hb, tq, tk), scope = _flash_tiles(q, group, budget)
+    with scope:
+        return flash_attention_bwd_pallas(
+            q, k, v, o, m, l, do, causal=causal, window=window, group=group,
+            tq=tq, tk=tk, hb=hb, interpret=interpret)
 
 
 _flash_fused.defvjp(_flash_fused_fwd, _flash_fused_bwd)
@@ -504,6 +520,8 @@ def flash_mha_op(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (batch) sharding leaves the per-grid-step (S, D) working set — the
     only thing ``attn_bwd_vmem_fits`` depends on — unchanged, so the
     predicate is already per-shard and the hint needs no arithmetic here.
+    The head block (``choose_attn_tiles``) divides the B·H rows the
+    launch sees.
     """
     del shard_dims
     B, S, H, D = q.shape

@@ -11,6 +11,8 @@ to each operation's ``op_name`` metadata, so the compiled computation is
 the same with or without it.  The model's parts are :data:`PARTS`
 (``embed``, ``attn``, ``ffn``, ``head``); everything a train step does
 after the gradients (grad-tier cast, clip, optimizer) is :data:`UPDATE`.
+Flash attention's launches over head blocks run under :data:`FLASH_ROWS`,
+which marks the path a shape took.
 
 :func:`stage_of` reads the stage of one compiled instruction from its
 ``op_name``.  Forward, backward and recompute come from the name stack JAX
@@ -26,6 +28,9 @@ import jax
 EMBED, ATTN, FFN, HEAD = "embed", "attn", "ffn", "head"
 PARTS = (EMBED, ATTN, FFN, HEAD)
 UPDATE = "update"
+# Flash launches that take a head block (many whole short sequences a grid
+# step); metadata only, read by no stage or part.
+FLASH_ROWS = "flash_rows"
 STAGES = ("forward", "backward", "recompute", "update")
 
 _SCOPES = re.compile(r"(?:^|[/(])(%s)(?=[/)]|$)" % "|".join(PARTS + (UPDATE,)))

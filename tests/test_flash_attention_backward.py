@@ -25,6 +25,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from repro.kernels import (
     attn_bwd_vmem_fits,
+    choose_attn_tiles,
     flash_attention_bwd_pallas,
     flash_attention_bwd_ref,
     flash_attention_pallas,
@@ -32,6 +33,7 @@ from repro.kernels import (
     fused_attn_hbm_bytes,
     unfused_attn_hbm_bytes,
 )
+from repro.kernels.btt_linear import VMEM_BUDGET
 from repro.models.attention import blockwise_attention
 
 
@@ -63,13 +65,14 @@ def _operands(bh_kv, group, S, D, dtype=jnp.float32, seed=None):
     return q, k, v, do
 
 
-def _kernel_grads(q, k, v, do, causal, window, group, tq=None, tk=None):
+def _kernel_grads(q, k, v, do, causal, window, group, tq=None, tk=None,
+                  hb=1):
     o, m, l = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                     group=group, tq=tq, tk=tk,
+                                     group=group, tq=tq, tk=tk, hb=hb,
                                      interpret=True, return_residuals=True)
     return flash_attention_bwd_pallas(q, k, v, o, m, l, do, causal=causal,
                                       window=window, group=group, tq=tq,
-                                      tk=tk, interpret=True)
+                                      tk=tk, hb=hb, interpret=True)
 
 
 def _oracle_grads(q, k, v, do, causal, window, group):
@@ -94,22 +97,27 @@ def _assert_close(got, want, tol, names=("dq", "dk", "dv")):
 # ---------------------------------------------------------------------------
 
 CASES = [
-    # (BH_kv, group, S, D, causal, window)
-    (2, 1, 256, 64, True, None),
-    (2, 4, 256, 64, True, None),      # GQA
-    (1, 2, 300, 80, True, None),      # ragged S and D
-    (2, 1, 256, 64, False, None),     # encoder (non-causal; the ATIS model)
-    (2, 2, 512, 64, True, 128),       # sliding window
-    (1, 1, 32, 64, False, None),      # the paper's S=32 regime, unpadded
+    # (BH_kv, group, S, D, causal, window, hb)
+    (2, 1, 256, 64, True, None, 1),
+    (2, 4, 256, 64, True, None, 1),      # GQA
+    (1, 2, 300, 80, True, None, 1),      # ragged S and D
+    (2, 1, 256, 64, False, None, 1),     # encoder (non-causal; the ATIS model)
+    (2, 2, 512, 64, True, 128, 1),       # sliding window
+    (1, 1, 32, 64, False, None, 1),      # the paper's S=32 regime, unpadded
+    # Head blocks: hb whole (batch·head) rows a grid step.
+    (12, 1, 32, 64, False, None, 12),    # ATIS at batch 1: one step
+    (48, 1, 32, 64, False, None, 16),    # ATIS at batch 4
+    (4, 4, 128, 64, True, None, 8),      # causal GQA: dK/dV summed in-step
+    (2, 2, 100, 80, True, 32, 4),        # window, ragged S and D
 ]
 
 
 @pytest.mark.parametrize("case", CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_bwd_kernel_matches_dense_autodiff(case, dtype):
-    bh_kv, group, S, D, causal, window = case
+    bh_kv, group, S, D, causal, window, hb = case
     q, k, v, do = _operands(bh_kv, group, S, D, dtype)
-    got = _kernel_grads(q, k, v, do, causal, window, group)
+    got = _kernel_grads(q, k, v, do, causal, window, group, hb=hb)
     want = _oracle_grads(q, k, v, do, causal, window, group)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     _assert_close(got, want, tol)
@@ -120,13 +128,18 @@ def test_bwd_kernel_matches_dense_autodiff(case, dtype):
 # ---------------------------------------------------------------------------
 
 SINGLE_TILE = [
-    # (BH_kv, group, S, causal, window) — D = 128, tq = tk = S: no padding,
-    # one grid step per (head, q-block), identical GEMMs in identical order.
-    (2, 2, 256, True, None),
-    (1, 1, 128, False, None),
-    (2, 1, 32, True, None),
-    (2, 1, 32, False, None),
-    (1, 1, 256, True, 64),
+    # (BH_kv, group, S, causal, window, hb) — D = 128, tq = tk = S: no
+    # padding, one grid step per (head, q-block), identical GEMMs in
+    # identical order.  hb > 1: a head block of whole sequences, the same
+    # GEMMs per head and dK/dV summed over the group ascending.
+    (2, 2, 256, True, None, 1),
+    (1, 1, 128, False, None, 1),
+    (2, 1, 32, True, None, 1),
+    (2, 1, 32, False, None, 1),
+    (1, 1, 256, True, 64, 1),
+    (12, 1, 32, False, None, 12),
+    (48, 1, 32, False, None, 16),
+    (4, 4, 128, True, None, 8),
 ]
 
 
@@ -135,14 +148,14 @@ def test_bwd_kernel_bitmatches_ref_single_tile(case):
     """One grid step per (head, q-block) => the kernel issues the
     reference's exact GEMMs in the reference's accumulation order; results
     must be bit-identical (both paths fed the same forward (o, m, l))."""
-    bh_kv, group, S, causal, window = case
+    bh_kv, group, S, causal, window, hb = case
     q, k, v, do = _operands(bh_kv, group, S, 128)
     o, m, l = flash_attention_pallas(q, k, v, causal=causal, window=window,
                                      group=group, tq=S, tk=S, interpret=True,
                                      return_residuals=True)
     got = flash_attention_bwd_pallas(q, k, v, o, m, l, do, causal=causal,
                                      window=window, group=group, tq=S, tk=S,
-                                     interpret=True)
+                                     hb=hb, interpret=True)
     want = flash_attention_bwd_ref(q, k, v, o, m, l, do, causal=causal,
                                    window=window, group=group)
     for name, u, w in zip(("dq", "dk", "dv"), got, want):
@@ -154,7 +167,7 @@ def test_bwd_kernel_bitmatches_ref_single_tile(case):
 def test_bwd_kernel_close_to_ref_multi_tile(case):
     """Tiled launches reorder the f32 accumulations; the kernel must still
     track the reference to tolerance on padded/multi-tile shapes."""
-    bh_kv, group, S, D, causal, window = case
+    bh_kv, group, S, D, causal, window, _ = case
     q, k, v, do = _operands(bh_kv, group, S, D)
     o, m, l = flash_attention_pallas(q, k, v, causal=causal, window=window,
                                      group=group, tq=128, tk=128,
@@ -259,6 +272,34 @@ def test_long_sequences_exceed_real_budget():
     grows with S) must route to the blockwise path."""
     assert not attn_bwd_vmem_fits(32768, 128, 2)
     assert attn_bwd_vmem_fits(32, 64, 4)          # the paper's regime fits
+
+
+@pytest.mark.parametrize("S,D,itemsize,rows,group,hb", [
+    (32, 64, 4, 512 * 12, 1, 32),     # ATIS b512s32: 192 steps, not 6,144
+    (32, 64, 4, 12, 1, 12),           # ATIS b1s32: all 12 heads, one step
+    (32, 64, 4, 4 * 12, 1, 24),
+    (128, 64, 4, 16, 4, 8),           # GQA: a multiple of the group
+    (32, 64, 4, 1, 1, 1),             # one head: nothing to block
+])
+def test_chooser_takes_a_head_block_where_the_sequence_is_one_tile(
+        S, D, itemsize, rows, group, hb):
+    got, tq, tk, sp, _, vmem = choose_attn_tiles(S, D, itemsize, rows=rows,
+                                                 group=group)
+    assert (got, tq, tk, sp) == (hb, S, S, S)   # nq = nk = 1
+    assert rows % got == 0 and got % group == 0
+    assert vmem <= VMEM_BUDGET
+
+
+@pytest.mark.parametrize("S,rows,group", [
+    (512, 8 * 32, 4),                 # Granite b8s512
+    (4096, 32, 4),                    # Granite b1s4096
+])
+def test_chooser_keeps_one_head_a_step_for_long_sequences(S, rows, group):
+    """Granite's shapes: today's (256, 256) tiles, one (batch, head) pair
+    a grid step."""
+    got = choose_attn_tiles(S, 128, 2, rows=rows, group=group)
+    assert got[:3] == (1, 256, 256)
+    assert got == (1, *choose_attn_tiles(S, 128, 2)[1:])
 
 
 # ---------------------------------------------------------------------------

@@ -132,8 +132,8 @@ def test_paper_atis_models_fit_full_envelope(n_enc, fused_attn):
 def test_attn_kernel_rows_are_chooser_derived(arch):
     """With fused_attn the FWD/BWD attn_kernel_vmem rows must equal the
     flash backward kernel's own tile-chooser numbers (recomputed here
-    independently); without it, 0 — no Pallas launch on the blockwise
-    path."""
+    independently, at the batch's B·H rows, which set the head block);
+    without it, 0 — no Pallas launch on the blockwise path."""
     from repro.kernels.flash_backward import attn_stage_vmem_bytes
 
     cfg = _tt_config(arch)
@@ -143,6 +143,8 @@ def test_attn_kernel_rows_are_chooser_derived(arch):
                                   batch=BATCH, seq=SEQ)
     for stage in ("FWD", "BWD"):
         expect = attn_stage_vmem_bytes(SEQ, cfg.d_head, itemsize,
+                                       rows=BATCH * cfg.n_heads,
+                                       group=cfg.n_heads // cfg.n_kv_heads,
                                        stage=stage, fused=True)
         assert led_on[stage].entry("attn_kernel_vmem").nbytes == expect
         assert expect <= URAM_BUDGET_BYTES

@@ -3,7 +3,8 @@
 No chip is needed: the TPU compiler is installed with ``jaxlib`` and
 compiles for a described (not attached) v5e.  Each test lowers one kernel
 with ``interpret=False`` at the paper's ATIS widths (d_model 768, TT rank
-12, 12 heads of 64, K = batch * seq = 32 and 4096 rows) and asserts that
+12, 12 heads of 64, K = batch * seq = 32 and 4096 rows; flash attention
+also over 512 sequences in head blocks) and asserts that
 Mosaic produced a ``tpu_custom_call``: a kernel that breaks the TPU block
 rules or cannot be lowered fails here instead of on the chip.
 
@@ -22,7 +23,10 @@ from repro.kernels.btt_backward import btt_backward_pallas
 from repro.kernels.btt_ffn import btt_ffn_bwd_pallas, btt_ffn_pallas
 from repro.kernels.btt_linear import btt_linear_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.flash_backward import flash_attention_bwd_pallas
+from repro.kernels.flash_backward import (
+    choose_attn_tiles,
+    flash_attention_bwd_pallas,
+)
 from repro.kernels.fused_update import fused_adamw_update, fused_sgd_update
 
 D_MODEL, RANK, D_FF = 768, 12, 768
@@ -117,6 +121,35 @@ def test_flash_backward_compiles(one_chip, causal):
         lambda q, k, v, o, m, l, do: flash_attention_bwd_pallas(
             q, k, v, o, m, l, do, causal=causal, interpret=False),
         q, k, v, o, *stats, _spec(one_chip, (HEADS, SEQ, D_HEAD)))
+
+
+# The b512s32 cell's attention: 512 sequences x 12 heads, each whole
+# sequence one tile, so the chooser gives a head block.
+ROWS = 512 * HEADS
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_forward_head_block_compiles(one_chip, causal):
+    hb = choose_attn_tiles(SEQ, D_HEAD, 4, rows=ROWS)[0]
+    assert hb > 1
+    qkv = [_spec(one_chip, (ROWS, SEQ, D_HEAD)) for _ in range(3)]
+    _assert_mosaic(
+        lambda q, k, v: flash_attention_pallas(
+            q, k, v, causal=causal, hb=hb, interpret=False,
+            return_residuals=True),
+        *qkv)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_backward_head_block_compiles(one_chip, causal):
+    hb = choose_attn_tiles(SEQ, D_HEAD, 4, rows=ROWS)[0]
+    assert hb > 1
+    big = [_spec(one_chip, (ROWS, SEQ, D_HEAD)) for _ in range(4)]
+    stats = [_spec(one_chip, (ROWS, SEQ)) for _ in range(2)]
+    _assert_mosaic(
+        lambda q, k, v, o, m, l, do: flash_attention_bwd_pallas(
+            q, k, v, o, m, l, do, causal=causal, hb=hb, interpret=False),
+        *big, *stats, _spec(one_chip, (ROWS, SEQ, D_HEAD)))
 
 
 def _pu_tree(sh):

@@ -165,3 +165,30 @@ def test_train_marks_a_step_that_compiled_again(monkeypatch, capsys):
     assert [ln for ln in lines if "RECOMPILED" in ln][0].startswith(
         "[train] step     3")
     assert np.all(np.isfinite(out["losses"]))
+
+
+@pytest.mark.parametrize("B,S,H,KV,marked", [
+    (4, 32, 4, 4, True),      # whole short sequences: a head block
+    (2, 32, 8, 2, True),      # GQA
+    (1, 512, 4, 2, False),    # (256, 256) tiles: one head a grid step
+])
+def test_flash_rows_marks_head_block_launches(B, S, H, KV, marked):
+    """Both flash launches of a head block run under ``FLASH_ROWS``, so a
+    compiled instruction's ``op_name`` shows which shapes took it; other
+    shapes' launches carry no such scope."""
+    from repro.kernels import flash_mha_op
+
+    q = jax.ShapeDtypeStruct((B, S, H, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((B, S, KV, 16), jnp.float32)
+
+    def loss(q_, k_, v_):
+        return flash_mha_op(q_, k_, v_, causal=True, interpret=True).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    scope = re.compile(r"(?:^|[/(])%s[/)]" % tracing.FLASH_ROWS)
+    for kernel in ("flash_fwd", "flash_bwd"):
+        names = [n for n in op_names(text).values()
+                 if f"/{kernel}/" in n and n.startswith("jit(")]
+        assert names, kernel
+        assert {bool(scope.search(n)) for n in names} == {marked}, kernel
