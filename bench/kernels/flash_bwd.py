@@ -5,6 +5,7 @@ from bench.workcount import itemsize
 
 
 def work(call, ctx):
-    s, item = shape(ctx), itemsize(ctx["config"])
-    return (attention.backward(*s, ctx["config"]["model"]["causal"], item)[0],
-            *attention.backward_bytes(*s, item))
+    B, H, KV, S, D, Dv = shape(ctx)
+    causal, item = ctx["config"]["model"]["causal"], itemsize(ctx["config"])
+    return (attention.backward(B, H, KV, S, D, causal, item, Dv)[0],
+            *attention.backward_bytes(B, H, KV, S, D, item, Dv))

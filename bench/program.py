@@ -20,9 +20,41 @@ from bench import weights as bw
 from bench.workcount import kernel_calls
 
 
+def _replaced(obj, changes: dict):
+    """``obj`` with ``changes``: a nested section (a dict for a dataclass
+    field such as ``moe``) replaces that section's fields, and a list
+    stands for a tuple."""
+    fields = {}
+    for key, value in changes.items():
+        here = getattr(obj, key)
+        if isinstance(value, dict) and dataclasses.is_dataclass(here):
+            value = _replaced(here, value)
+        elif isinstance(value, list):
+            value = tuple(value)
+        fields[key] = value
+    return dataclasses.replace(obj, **fields)
+
+
+def differences(obj, want: dict, prefix: str = "") -> dict:
+    """``{name: (got, want)}`` of every key of ``want`` that ``obj`` does not
+    hold; a nested section is compared field by field (``moe.top_k``)."""
+    wrong = {}
+    for key, value in want.items():
+        got, name = getattr(obj, key), prefix + key
+        if isinstance(value, dict) and dataclasses.is_dataclass(got):
+            wrong.update(differences(got, value, name + "."))
+            continue
+        if isinstance(got, tuple):
+            got = list(got)
+        if got != value:
+            wrong[name] = (got, value)
+    return wrong
+
+
 def build_cfg(config: dict):
     """The model config through ``repro.launch.train.build``, checked key
-    by key against the configuration's file."""
+    by key against the configuration's file: its ``model`` section against
+    the config, its ``tt`` section against ``cfg.tt``."""
     from repro.launch.train import build
 
     b = config["build"]
@@ -31,20 +63,9 @@ def build_cfg(config: dict):
         kernel_flow=b["kernel_flow"], fused_attn=b["fused_attn"],
         fused_ffn=b["fused_ffn"], fp32=False, param_dtype=None,
         act_dtype=None, grad_dtype=None)
-    cfg = dataclasses.replace(build(ns), **config["replace"])
-    wrong = {}
-    for key, want in config["model"].items():
-        got = getattr(cfg, key)
-        if isinstance(got, tuple):
-            got = list(got)
-        if got != want:
-            wrong[key] = (got, want)
-    for key, want in config["tt"].items():
-        got = getattr(cfg.tt, key)
-        if isinstance(got, tuple):
-            got = list(got)
-        if got != want:
-            wrong["tt." + key] = (got, want)
+    cfg = _replaced(build(ns), config["replace"])
+    wrong = {**differences(cfg, config["model"]),
+             **differences(cfg.tt, config["tt"], "tt.")}
     if wrong:
         raise ValueError(f"the program's config differs from "
                          f"{config['name']}.json: {wrong}")
@@ -84,7 +105,7 @@ class Program:
         struct = param_struct(cfg)
         self.layout = bw.describe(struct)
         self.treedef = jax.tree.structure(struct)
-        self._make = bw.make_weights(self.layout)
+        self._make = bw.make_weights(self.layout, config["family"])
         self._init = jax.jit(self.opt.init)
         params, opt_state = self._fresh()
 
@@ -181,7 +202,7 @@ class Program:
         self.params = self.opt_state = self.compiled = None
 
 
-def reference_weights(layout, seed: int) -> dict:
+def reference_weights(layout, seed: int, family: str | None = None) -> dict:
     """The seed's weights again, by name, for the reference."""
-    leaves = bw.make_weights(layout)(bw.seed_words(seed))
+    leaves = bw.make_weights(layout, family)(bw.seed_words(seed))
     return {p: a for (p, _, _), a in zip(layout, leaves)}

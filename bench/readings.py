@@ -67,7 +67,7 @@ def readings(cell: dict, seeds, kinds=("program", "control", "half_batch")):
     for seed in seeds:
         batches = [tokens.batch(seed, i, traffic["batch"], traffic["seq"],
                                 config["model"]["vocab_size"]) for i in range(n)]
-        w = reference_weights(names, seed)
+        w = reference_weights(names, seed, config["family"])
         base = ref.run(w, batches)
         for kind in kinds:
             if kind in PROGRAM_KINDS:

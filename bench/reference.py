@@ -106,24 +106,25 @@ def rope(x, theta):
 
 
 def attention(q, k, v, causal, rnd):
-    """``q (B, S, H, D)``, ``k, v (B, S, KV, D)``; one KV head at a time."""
+    """``q (B, S, H, D)``, ``k (B, S, KV, D)``, ``v (B, S, KV, Dv)`` ->
+    ``(B, S, H Dv)``, softmax scale ``1 / sqrt(D)``; one KV head at a time."""
     B, S, H, D = q.shape
-    KV = k.shape[2]
+    KV, Dv = k.shape[2], v.shape[-1]
     qg = q.reshape(B, S, KV, H // KV, D).transpose(2, 0, 1, 3, 4)
     kt, vt = k.transpose(2, 0, 1, 3), v.transpose(2, 0, 1, 3)
     keep = jnp.tril(jnp.ones((S, S), bool)) if causal else None
 
     @jax.checkpoint
     def one(args):
-        qh, kh, vh = args                       # (B,S,G,D), (B,S,D), (B,S,D)
+        qh, kh, vh = args                       # (B,S,G,D), (B,S,D), (B,S,Dv)
         s = dot("bqgd,bcd->bgqc", qh, kh, rnd) / math.sqrt(D)
         if keep is not None:
             s = jnp.where(keep, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         return dot("bgqc,bcd->bqgd", p, vh, rnd)
 
-    out = jax.lax.map(one, (qg, kt, vt))         # (KV, B, S, G, D)
-    return out.transpose(1, 2, 0, 3, 4).reshape(B, S, H * D)
+    out = jax.lax.map(one, (qg, kt, vt))         # (KV, B, S, G, Dv)
+    return out.transpose(1, 2, 0, 3, 4).reshape(B, S, H * Dv)
 
 
 def padded_vocab(vocab: int) -> int:

@@ -212,7 +212,7 @@ def main(argv=None, device: dict | None = None) -> dict:
     from bench import check, tokens
     from bench.program import Program, reference_weights
     from bench.reference import Reference
-    from bench.workcount import on_chip_share, step_work
+    from bench.workcount import context, on_chip_share, step_work
 
     config, traffic = cell["config"], cell["traffic"]
     seed = args.seed
@@ -241,7 +241,8 @@ def main(argv=None, device: dict | None = None) -> dict:
     del prog
 
     t_ref = time.perf_counter()
-    ref = Reference(config, traffic).run(reference_weights(layout, seed), ours)
+    ref = Reference(config, traffic).run(
+        reference_weights(layout, seed, config["family"]), ours)
     reference_s = time.perf_counter() - t_ref
     numbers = check.gaps(first, ref)
     numbers["inputs_differ"] = differ
@@ -261,9 +262,9 @@ def main(argv=None, device: dict | None = None) -> dict:
             "check_losses": first["losses"], "reference_losses": ref["losses"],
             "window_first_loss": w["losses"][0], "window_last_loss": w["losses"][-1],
             "kernels": kernels,
-            "kernel_bytes_on_chip_pct": on_chip_share(
-                calls, {"config": config, "traffic": traffic,
-                        "params": work["params"], "calls": calls}),
+            "kernel_bytes_on_chip_pct": on_chip_share(calls, context(
+                {"config": config, "traffic": traffic, "work": work,
+                 "calls": calls})),
             "numbers": {k: numbers[k] for k in check.NUMBERS},
             "worst_leaf": numbers["worst_leaf"],
             "leaves_kept": numbers["leaves_kept"], "leaves": numbers["leaves"],

@@ -1,5 +1,5 @@
 """A training step's least work, from the model's shapes (the
-configuration's file) and the weights the benchmark made.
+configuration's file and the layout of the program's parameters).
 
 ``step_work`` gives the whole step's least FLOPs, for MFU, from the file of
 the configuration's model family, ``bench/models/<family>.py``.
@@ -15,9 +15,10 @@ take into account.
 """
 from __future__ import annotations
 
+import math
 import re
 
-from bench import spec
+from bench import spec, weights
 
 
 # --- shapes shared by the kernel files ---------------------------------------
@@ -35,15 +36,30 @@ def vocab_padded(v: int) -> int:
     return (v + 255) // 256 * 256
 
 
-def fit_width(config: dict, x: int) -> int:
-    """The largest of the model's TT matrix sides that fits in a side the
-    kernels padded to ``x``."""
-    m, tt = config["model"], config["tt"]
-    widths = {m["d_model"], m["n_heads"] * m["d_head"],
-              m["n_kv_heads"] * m["d_head"], m["d_ff"]}
-    if "head" in tt["scope"] and not m["tie_embeddings"]:
-        widths.add(vocab_padded(m["vocab_size"]))
-    return max(w for w in widths if w <= x)
+TILE = 512  # the widest padding a BTT kernel gives a side (btt_linear's lanes)
+
+
+def fit_matrix(ctx: dict, rows: int, cols: int) -> tuple[int, int]:
+    """The model's TT matrix ``(out, in)`` that the kernels padded to
+    ``(rows, cols)``, or its transpose.
+
+    The matrices are the layout's (``ctx["sides"]``, by owner), matched
+    whole, with less than a ``TILE`` of padding on each side: one side
+    matched alone can reach another matrix's side (an in-width of 2,816
+    padded to 3,072, a query width).  A transpose (the head's backward runs
+    ``btt_linear`` on its transposed factors) is matched only where no
+    matrix fits as it stands; of those that fit, the largest."""
+    pairs = {tuple(p) for p in ctx["sides"].values()}
+
+    def fitting(cands):
+        return [p for p in cands
+                if 0 <= rows - p[0] < TILE and 0 <= cols - p[1] < TILE]
+
+    fits = fitting(pairs) or fitting({p[::-1] for p in pairs})
+    if not fits:
+        raise ValueError(f"no TT matrix of the model fits in ({rows}, {cols}):"
+                         f" {sorted(pairs)}")
+    return max(fits, key=lambda p: (p[0] * p[1], p))
 
 
 def tokens(ctx: dict, padded_rows: int) -> int:
@@ -53,21 +69,34 @@ def tokens(ctx: dict, padded_rows: int) -> int:
     return min(padded_rows, t["batch"] * t["seq"])
 
 
+def groups(call: dict) -> tuple[int, list]:
+    """``(G, operands)`` of a call whose count expects 2-D operands: a call
+    whose operands carry leading axes besides (a kernel under ``vmap`` over
+    experts) runs ``G`` groups, each on its operands without those axes."""
+    ops = [tuple(o) for o in call["operands"]]
+    lead = ops[0][:-2]
+    if any(o[:-2] != lead for o in ops):
+        raise ValueError(f"{call['kernel']}: operands with unlike group "
+                         f"axes {ops}; such a call needs a count of its own")
+    return math.prod(lead), [o[-2:] for o in ops]
+
+
+def grouped(G: int, counted) -> tuple:
+    """``(FLOPs, bytes read, bytes written)`` of one group, times ``G``."""
+    fl, ins, outs = counted
+    return G * fl, [G * b for b in ins], [G * b for b in outs]
+
+
 # --- the whole step ------------------------------------------------------------
 
-def _size(shape) -> int:
-    out = 1
-    for x in shape:
-        out *= x
-    return out
-
-
 def step_work(config: dict, traffic: dict, layout) -> dict:
-    """``{"step_flops", "params"}``: the step's least FLOPs, from the model
-    family's file, and the parameter count."""
+    """``{"step_flops", "params", "sides"}``: the step's least FLOPs, from
+    the model family's file, the parameter count, and the ``(out, in)`` of
+    every TT matrix of the layout, by owner."""
     family = spec.load_module("models", config["family"])
     return {"step_flops": family.step_flops(config, traffic, layout),
-            "params": sum(_size(s) for _, s, _ in layout)}
+            "params": sum(math.prod(s) for _, s, _ in layout),
+            "sides": weights.matrix_sides(layout)}
 
 
 def least_seconds(ops, peaks: dict) -> float:
@@ -135,8 +164,7 @@ def _counted(call: dict, ctx: dict):
 
 def call_work(call: dict, ctx: dict) -> tuple[int, int]:
     """The least ``(FLOPs, bytes)`` of one execution of a kernel call.
-    ``ctx`` holds the run's ``config``, ``traffic``, parameter count
-    ``params`` and compiled ``calls``."""
+    ``ctx`` is ``context``'s."""
     fl, ins, outs = _counted(call, ctx)
     return fl, sum(ins) + sum(outs)
 
@@ -157,8 +185,12 @@ def on_chip_bytes(call: dict, ctx: dict) -> int:
 
 
 def context(record: dict) -> dict:
+    """What a kernel's count sees: the run's ``config``, ``traffic``,
+    parameter count ``params``, TT matrix ``sides`` and compiled ``calls``."""
+    work = record["work"]
     return {"config": record["config"], "traffic": record["traffic"],
-            "params": record["work"]["params"], "calls": record["calls"]}
+            "params": work["params"], "sides": work["sides"],
+            "calls": record["calls"]}
 
 
 def family_share(record: dict, kernels) -> float | None:

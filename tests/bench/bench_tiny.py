@@ -44,3 +44,30 @@ def tiny_cell(name: str, float32: bool = False) -> dict:
 
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1,
        "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}}
+
+
+MOE_ARCHS = ("qwen2-moe-a2.7b", "llama4-maverick-400b-a17b")
+
+
+def moe_config(arch: str, rank: int = 16) -> dict:
+    """A configuration file's contents for ``arch`` (one of the program's
+    mixture-of-experts archs) at the program's own scaled-down widths, TT
+    compressed on the kernel path, its ``moe`` section nested as a file
+    states it."""
+    from repro.configs import get_config
+
+    small = get_config(arch).scaled_down()
+    moe = {"num_experts": small.moe.num_experts, "d_expert": small.moe.d_expert,
+           "shared_d_ff": small.moe.shared_d_ff}
+    widths = {"num_layers": small.num_layers, "d_model": small.d_model,
+              "n_heads": small.n_heads, "n_kv_heads": small.n_kv_heads,
+              "d_head": small.d_head, "d_ff": small.d_ff,
+              "vocab_size": small.vocab_size, "dtype": "float32", "moe": moe}
+    return {"name": arch, "arch": arch, "family": "dense_transformer",
+            "build": {"tt": True, "tt_rank": rank, "kernel_flow": True,
+                      "fused_attn": True, "fused_ffn": True, "fused": True},
+            "replace": widths,
+            "model": dict(widths, moe=dict(moe, top_k=small.moe.top_k,
+                                           every=small.moe.every)),
+            "tt": {"mode": "tt", "rank": rank, "flow": "kernel",
+                   "scope": ["attn", "ffn", "embed", "head"]}}

@@ -94,10 +94,13 @@ def test_kernel_calls_read_where_each_operand_lives():
 
 def _ctx(name="atis6-tt.b1s32", calls=None):
     from bench import spec
+    from bench.program import layout
+    from bench.weights import matrix_sides
 
     cell = spec.cell(name)
     return {"config": cell["config"], "traffic": cell["traffic"],
-            "params": 1000, "calls": calls or {}}
+            "params": 1000, "sides": matrix_sides(layout(cell["config"])),
+            "calls": calls or {}}
 
 
 def test_call_work_counts_bytes_from_shapes_alone():
